@@ -10,15 +10,13 @@ Three sections:
   * **grad rows**: ``value_and_grad`` over a dispatched GEMM per mode (one
     forward + the two phase-dispatched backward GEMMs through the custom_vjp
     layer) so the regression gate covers gradient-dispatch overhead, and
-  * the **hot-path section**: a GemmPlan sweep of the vectorized Pallas
-    engine at (M,N,K) = (256, 256, 1024), measured against the seed per-k
-    scalar-loop kernel (kept as ``impl="loop"``) with a bit-exactness check —
-    the speedup this PR's execution engine is accountable for.
+  * **ragged rows**: the sorted-segment MoE kernel against the grouped FDP
+    reference, bit-exactness asserted.
 
 ``--json out.json`` additionally writes every row machine-readably
 (per-impl/per-shape wall time + modeled energy) so benchmark trajectories
 can be tracked across commits (CI uploads it as an artifact); ``--quick``
-trims the table and skips the hot-path sweep for bounded CI lanes.
+trims the shapes for bounded CI lanes.
 """
 
 import argparse
@@ -30,8 +28,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import (AccumulatorSpec, FP32, GemmPlan, generate_gemm,
-                        plan_gemm)
+from repro.core import AccumulatorSpec, FP32, generate_gemm, plan_gemm
 from repro.core.energy import FREQ_HZ, gemm_power
 from repro.kernels import ops as kops
 
@@ -50,12 +47,6 @@ QUICK_SHAPES = [(32, 128, 32)]
 # negligible bench cost (no FDP kernels run for them).
 QUICK_NATIVE_ANCHORS = [(256, 1024, 256), (384, 1536, 384), (512, 2048, 512)]
 SPECS = [AccumulatorSpec.paper_91bit(), AccumulatorSpec(9, 6, -20)]
-
-# Hot-path acceptance shape and the seed kernel's hardcoded tile.
-HOT_SHAPE = (256, 256, 1024)
-SEED_TILE = (32, 32, 128)
-SWEEP_TILES = [(32, 32, 128), (32, 32, 512), (64, 64, 512), (128, 128, 512),
-               (128, 128, 1024)]
 
 ROWS: list = []                 # machine-readable mirror of every CSV line
 
@@ -200,67 +191,6 @@ def _best_of(fn, reps=2):
     return best, out
 
 
-def run_hotpath():
-    """Plan sweep + seed-kernel comparison at HOT_SHAPE (the PR's acceptance
-    measurement): vectorized engine vs the seed per-k loop kernel at the
-    seed's hardcoded tile, bit-exact, for both seed-bench accumulators."""
-    rng = np.random.default_rng(1)
-    M, N, K = HOT_SHAPE
-    a = jnp.asarray(rng.standard_normal((M, K)), jnp.float32)
-    b = jnp.asarray(rng.standard_normal((K, N)), jnp.float32)
-    flops = 2 * M * K * N
-    speedups, exact = {}, True
-
-    for spec in SPECS:
-        print(f"\n# hot path (M,N,K)=({M},{N},{K}), spec={spec.describe()}")
-        print("name,seconds_per_call,derived")
-
-        # the seed kernel: per-k fori_loop body at the seed's hardcoded tile
-        t_seed, out_seed = _best_of(
-            lambda: kops.fdp_gemm(a, b, spec=spec, plan=GemmPlan(*SEED_TILE),
-                                  impl="loop"))
-        emit(f"pallas_seed_loop_w{spec.width}_"
-             f"{'x'.join(map(str, SEED_TILE))}", t_seed,
-             f"GFLOPs={flops/t_seed/1e9:.3f}",
-             shape=(M, K, N), spec=spec, impl="pallas_loop", unit="s")
-
-        best = (None, float("inf"), None)
-        for bm, bn, bk in SWEEP_TILES:
-            t, out = _best_of(
-                lambda: kops.fdp_gemm(a, b, spec=spec,
-                                      plan=GemmPlan(bm, bn, bk)))
-            emit(f"pallas_vector_w{spec.width}_{bm}x{bn}x{bk}", t,
-                 f"GFLOPs={flops/t/1e9:.3f}|speedup={t_seed/t:.1f}x",
-                 shape=(M, K, N), spec=spec, impl="pallas_vector", unit="s")
-            if t < best[1]:
-                best = ((bm, bn, bk), t, out)
-
-        plan = plan_gemm(M, N, K, fmt=FP32, spec=spec)
-        t_plan, out_plan = _best_of(
-            lambda: kops.fdp_gemm(a, b, spec=spec, plan=plan))
-        emit(f"pallas_vector_planned_w{spec.width}_"
-             f"{plan.bm}x{plan.bn}x{plan.bk}", t_plan,
-             f"GFLOPs={flops/t_plan/1e9:.3f}|source={plan.source}"
-             f"|speedup={t_seed/t_plan:.1f}x",
-             shape=(M, K, N), spec=spec, impl="pallas_vector_planned",
-             unit="s")
-
-        exact &= bool(jnp.array_equal(out_seed, out_plan)) and \
-            bool(jnp.array_equal(out_seed, best[2]))
-        speedups[f"w{spec.width}"] = t_seed / min(t_plan, best[1])
-        emit(f"hotpath_w{spec.width}", 0,
-             f"best_tile={best[0]}"
-             f"|speedup={speedups[f'w{spec.width}']:.1f}x|bitexact={exact}")
-
-    top = max(speedups.values())
-    detail = "|".join(f"{k}={v:.1f}x" for k, v in speedups.items())
-    print()
-    emit("hotpath_summary", 0, f"{detail}|best={top:.1f}x|bitexact={exact}")
-    assert exact, "vectorized engine output diverged from the seed kernel"
-    assert top >= 5.0, (
-        f"hot-path speedup {detail} never reached the 5x acceptance bar")
-
-
 # Ragged (MoE expert) GEMM: tokens sorted by expert. (T, d, f, E).
 RAGGED_CASES = [(256, 128, 128, 8)]
 QUICK_RAGGED_CASES = [(128, 64, 64, 4)]
@@ -345,7 +275,6 @@ def run(quick: bool = False, json_path: str | None = None):
     else:
         run_table()
         run_grad_rows()
-        run_hotpath()
         run_ragged_rows()
     if json_path:
         doc = {
@@ -367,7 +296,7 @@ def main(argv=None):
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write machine-readable rows (BENCH_gemm.json)")
     ap.add_argument("--quick", action="store_true",
-                    help="small shapes, no hot-path sweep (CI lane)")
+                    help="small shapes (CI lane)")
     args = ap.parse_args(argv)
     run(quick=args.quick, json_path=args.json)
 

@@ -37,7 +37,7 @@ ill-conditioned ``solve`` workload is opt-in (``--validators solve,...``).
 Usage:
     PYTHONPATH=src python scripts/refresh_plans.py --reduced            # all
     PYTHONPATH=src python scripts/refresh_plans.py --only dbrx_132b --reduced
-    PYTHONPATH=src python scripts/refresh_plans.py --reduced --jobs 3
+    JAX_PLATFORMS=cpu PYTHONPATH=src python scripts/refresh_plans.py --reduced --jobs 3
     PYTHONPATH=src python scripts/refresh_plans.py --only paper_mlp --reduced \
         --check     # recompute from the saved trace, compare to checked-in
     PYTHONPATH=src python scripts/refresh_plans.py --schedules
@@ -527,7 +527,8 @@ def main(argv=None):
                          "the search end-to-end ('none' disables; the "
                          "ill-conditioned 'solve' workload is opt-in)")
     ap.add_argument("--jobs", type=int, default=1,
-                    help="process-parallel arch fan-out")
+                    help="process-parallel arch fan-out (CPU only: an "
+                         "accelerator serves one process at a time)")
     ap.add_argument("--check", action="store_true",
                     help="recompute and compare against the checked-in plan "
                          "instead of writing (CI reproducibility gate)")
@@ -543,6 +544,9 @@ def main(argv=None):
                          "their saved traces (no recalibration/search)")
     args = ap.parse_args(argv)
     args.out = os.path.abspath(args.out)
+    if args.jobs > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise SystemExit("--jobs > 1 starts one JAX process per arch; run it "
+                         "with JAX_PLATFORMS=cpu (a chip takes one process)")
     if args.schedules:
         refresh_schedules(args)
         return

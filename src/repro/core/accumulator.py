@@ -8,7 +8,13 @@ carrying a 16-bit digit plus carry headroom, so the whole algebra runs on the
 vector unit (VPU) with plain int32 adds/shifts — exactly the kind of substrate
 the MXU-adjacent VPU is good at.
 
-Normative semantics (see DESIGN.md §2.2):
+The limb algebra is written over *planes*: a register is a sequence of L
+int32 arrays of one shape, limb 0 first. That is the layout the Pallas
+kernels keep in VMEM, ``(L, bm, bn)``, so every plane is a whole number of
+(8, 128) vector tiles; the ``(..., L)`` array forms below (``carry_normalize``,
+``to_float``, ...) are thin wrappers that unstack the last axis.
+
+Normative semantics:
   * value(limbs) = Σ_l limbs[l] · 2^(lsb + 16·l)   (limbs int32, signed)
   * products are quantized ONCE at entry: round-toward-zero at 2^lsb
     (``trunc``, hardware default — drops the wires below lsb) or RNE,
@@ -104,9 +110,24 @@ class AccumulatorSpec:
         return cls(ovf=ovf, msb=msb, lsb=lsb)
 
 
-def zeros(spec: AccumulatorSpec, shape: Sequence[int] = ()) -> Array:
-    """Fresh accumulator state: shape (*shape, num_limbs) int32."""
-    return jnp.zeros((*shape, spec.num_limbs), dtype=jnp.int32)
+Planes = Sequence[Array]
+
+
+def _unstack(limbs: Array) -> list:
+    return [limbs[..., l] for l in range(limbs.shape[-1])]
+
+
+def _stack(planes: Planes) -> Array:
+    return jnp.stack(planes, axis=-1)
+
+
+_LIMB_SHIFT = LIMB_BITS.bit_length() - 1
+
+
+def _limb_split(q: Array) -> tuple:
+    """Grid bit offset -> (limb index, sub-shift 0..15): floor division by
+    LIMB_BITS as an arithmetic shift, so it lowers to plain VPU shifts."""
+    return jnp.right_shift(q, _LIMB_SHIFT), q & (LIMB_BITS - 1)
 
 
 # ---------------------------------------------------------------------------
@@ -134,65 +155,38 @@ def _product_digits(a: Decoded, b: Decoded) -> tuple:
     return d0, d1, d2
 
 
-def product_limbs(spec: AccumulatorSpec, a: Decoded, b: Decoded) -> Array:
-    """Exact limb contributions of the products a*b (elementwise), quantized
-    at 2^lsb per ``spec.round_mode``. Result: int32 (*batch, num_limbs); each
-    limb's magnitude is < 2^17, so up to SAFE_CHUNK results may be summed
-    before ``carry_normalize``.
+def product_planes(spec: AccumulatorSpec, a: Decoded, b: Decoded, *,
+                   reduce_leading: bool = False) -> list:
+    """Exact limb contributions of the products a*b (elementwise, operands
+    broadcast), quantized at 2^lsb per ``spec.round_mode``, as L planes.
+    Each limb's magnitude is < 2^17, so up to SAFE_CHUNK contributions may be
+    summed before ``normalize_planes``. ``reduce_leading`` sums each plane
+    over the leading axis as soon as it is formed (the GEMM hot path: a K
+    sub-chunk of products never exists as an L-fold tensor).
 
     The significand product is computed exactly in int32 via 12-bit digit
     splitting (24x24 -> 48 bits as three 16-bit digits), then aligned to the
-    grid with a uniform shift. Dropping the bits below position 0 of the
-    aligned non-negative magnitude implements round-toward-zero of the signed
-    product exactly.
-    """
+    grid with a uniform shift. Pieces are placed as MAGNITUDES and the sign
+    applied after: dropping below-limb-0 pieces of the non-negative form
+    implements round-toward-zero exactly (a sign-folded two's-complement form
+    would floor instead, off by 1 ulp for negative products)."""
     L = spec.num_limbs
-    digits = jnp.stack(_product_digits(a, b), axis=-1)    # (*batch, 3)
-
-    e_prod = a.exp + b.exp                                # exponent of digit 0
-    q = e_prod - spec.lsb                                 # grid bit offset
+    digits = _product_digits(a, b)
+    q = a.exp + b.exp - spec.lsb                          # grid bit offset
     sign = 1 - 2 * (a.sign ^ b.sign)                      # +1 / -1
-
-    limbs = _place_digits(digits, q, sign, L, spec)
-    # zero / special handling: zero mantissa -> all-zero contribution already.
-    return limbs
-
-
-def product_limb_block_sum(spec: AccumulatorSpec, a: Decoded, b: Decoded,
-                           axis: int = 0) -> Array:
-    """``jnp.sum(product_limbs(spec, a, b), axis=axis)`` without ever
-    materializing the (*batch, L) contribution tensor — the GEMM hot path.
-
-    The sum is computed limb-by-limb over small (*batch) slabs so the working
-    set stays cache-resident on CPU (and VMEM-bounded on TPU); int32 addition
-    is exact and commutative, so the result is bit-identical to the
-    materialized form. The caller owns the SAFE_CHUNK headroom budget for
-    the reduced axis."""
-    assert axis == 0, "the fused block sum reduces the leading axis"
-    L = spec.num_limbs
-    digits = _product_digits(a, b)                        # 3 x (*batch)
-    e_prod = a.exp + b.exp
-    q = e_prod - spec.lsb
-    sign = 1 - 2 * (a.sign ^ b.sign)
-    j0 = jnp.floor_divide(q, LIMB_BITS)                   # limb of digit 0
-    r = (q - j0 * LIMB_BITS).astype(jnp.int32)            # 0..15 sub-shift
+    j0, r = _limb_split(q)                                # limb of digit 0
     inc = (_rne_increment(digits, q) * sign
            if spec.round_mode == "rne" else None)         # lands on limb 0
     # compact 4-piece form: digit k's low part lands at limb j0+k, its high
     # part at j0+k+1, so piece i = lo[i] + hi[i-1] (|piece| < 2^17, the
-    # headroom contract behind SAFE_CHUNK) — 4 placements per limb instead of
-    # 6 (lo, hi) ones. Pieces are placed as MAGNITUDES and the sign applied
-    # after: dropping below-limb-0 pieces of the non-negative form implements
-    # round-toward-zero exactly (a sign-folded two's-complement form would
-    # floor instead, off by 1 ulp for negative products with dropped bits).
+    # headroom contract behind SAFE_CHUNK).
     lo = [jnp.left_shift(d, r) & LIMB_MASK for d in digits]
     hi = [jnp.right_shift(jnp.left_shift(d, r), LIMB_BITS) for d in digits]
     pieces = [lo[0], lo[1] + hi[0], lo[2] + hi[1], hi[2]]
     pieces = [p * sign for p in pieces]
     # Placement masks are shared across limbs (piece i of limb l needs
-    # j0 == l-i, which only depends on l-i): 0/1 multiplies through shared
-    # int32 masks measure ~1.5x faster than per-(l,i) compare+select chains
-    # on XLA:CPU, and each piece can only land on limbs -3..L-1.
+    # j0 == l-i, which only depends on l-i); each piece can only land on
+    # limbs -3..L-1.
     npieces = len(pieces)
     mask = {d: (j0 == d).astype(jnp.int32) for d in range(1 - npieces, L)}
     out = []
@@ -203,32 +197,13 @@ def product_limb_block_sum(spec: AccumulatorSpec, a: Decoded, b: Decoded,
                 acc_l = acc_l + piece * mask[l - i]
         if inc is not None and l == 0:
             acc_l = acc_l + inc
-        out.append(jnp.sum(acc_l, axis=axis))
-    return jnp.stack(out, axis=-1)
-
-
-def _place_digits(digits: Array, q: Array, sign: Array, L: int,
-                  spec: AccumulatorSpec) -> Array:
-    """Place base-2^16 ``digits`` (non-negative, weight 2^(16k)) at grid bit
-    offset ``q`` into L limbs, truncating below limb 0 (toward zero), with
-    optional RNE correction, then apply ``sign``."""
-    nd = digits.shape[-1]
-    j0 = jnp.floor_divide(q, LIMB_BITS)                   # limb of digit 0
-    r = q - j0 * LIMB_BITS                                # 0..15 sub-shift
-    r = r.astype(jnp.int32)
-    shifted_lo = jnp.left_shift(digits, r[..., None]) & LIMB_MASK
-    shifted_hi = jnp.right_shift(jnp.left_shift(digits, r[..., None]), LIMB_BITS)
-    # digit k contributes shifted_lo[k] at limb j0+k and shifted_hi[k] at j0+k+1
-    out = jnp.zeros((*digits.shape[:-1], L), dtype=jnp.int32)
-    for k in range(nd):
-        for off, part in ((k, shifted_lo[..., k]), (k + 1, shifted_hi[..., k])):
-            idx = j0 + off
-            onehot = (idx[..., None] == jnp.arange(L, dtype=jnp.int32))
-            out = out + jnp.where(onehot, part[..., None], 0)
-    if spec.round_mode == "rne":
-        out = out + _rne_correction(digits, q, L)
-    out = out * sign[..., None]
+        out.append(jnp.sum(acc_l, axis=0) if reduce_leading else acc_l)
     return out
+
+
+def product_limbs(spec: AccumulatorSpec, a: Decoded, b: Decoded) -> Array:
+    """``product_planes`` as one (*batch, L) int32 array."""
+    return _stack(product_planes(spec, a, b))
 
 
 def _rne_increment(digits, q: Array) -> Array:
@@ -246,8 +221,7 @@ def _rne_increment(digits, q: Array) -> Array:
     # bit at absolute product position p (0 <= p < 16*nd): p relative to grid = q + p
     # guard: grid pos -1 -> product bit pb = -1 - q ; valid if 0 <= pb < 16*nd
     def product_bit(pb):
-        k = jnp.floor_divide(pb, LIMB_BITS)
-        s = pb - k * LIMB_BITS
+        k, s = _limb_split(pb)
         val = jnp.zeros(pb.shape, jnp.int32)
         for kk in range(nd):
             val = val + jnp.where(k == kk,
@@ -273,74 +247,51 @@ def _rne_increment(digits, q: Array) -> Array:
     return inc.astype(jnp.int32)
 
 
-def _rne_correction(digits: Array, q: Array, L: int) -> Array:
-    """RNE increment as a (*batch, L) limb tensor (limb 0 carries it)."""
-    nd = digits.shape[-1]
-    inc = _rne_increment(tuple(digits[..., kk] for kk in range(nd)), q)
-    corr = jnp.zeros((*digits.shape[:-1], L), dtype=jnp.int32)
-    corr = corr.at[..., 0].set(inc)
-    return corr
-
-
 # ---------------------------------------------------------------------------
 # Carry normalization, wrap/saturate, read-out
 # ---------------------------------------------------------------------------
-def carry_normalize(spec: AccumulatorSpec, limbs: Array) -> Array:
+def normalize_planes(planes: Planes) -> list:
     """Propagate carries so limbs 0..L-2 are in [0, 2^16); the top limb keeps
     the full signed remainder (NOT masked to W bits).
 
     Keeping the intermediate state exact in the extended (16L + int32
     headroom)-bit range makes the result independent of chunk/block
     boundaries; the W-bit wrap/saturation is applied ONCE at read-out
-    (``finalize``/``to_float``), which for wrap is equivalent (mod-2^W is a
+    (``planes_to_float``/``to_float``), which for wrap is equivalent (mod-2^W is a
     ring homomorphism) and for saturate is the only order-invariant
     definition."""
-    L = spec.num_limbs
     out = []
-    carry = jnp.zeros(limbs.shape[:-1], dtype=jnp.int32)
-    for l in range(L - 1):
-        t = limbs[..., l] + carry
+    carry = 0
+    for p in planes[:-1]:
+        t = p + carry
         carry = jnp.right_shift(t, LIMB_BITS)      # arithmetic shift = floor
         out.append(t & LIMB_MASK)
-    out.append(limbs[..., L - 1] + carry)          # top limb: full int32
-    return jnp.stack(out, axis=-1)
+    out.append(planes[-1] + carry)                 # top limb: full int32
+    return out
 
 
-def finalize(spec: AccumulatorSpec, limbs: Array) -> Array:
-    """Apply the register's W-bit wrap or saturation to a carry-normalized
-    state (read-out step)."""
-    L = spec.num_limbs
-    return _apply_overflow(spec, limbs, limbs[..., L - 1])
-
-
-def _apply_overflow(spec: AccumulatorSpec, norm: Array, top: Array) -> Array:
-    """Wrap or saturate the register at W bits (two's complement)."""
-    L, W = spec.num_limbs, spec.width
-    top_bits = W - LIMB_BITS * (L - 1)              # 1..16 significant top bits
-    # wrap: sign-extend the top limb from top_bits
-    shift = 32 - top_bits
-    wrapped_top = jnp.right_shift(jnp.left_shift(top, shift), shift)
-    if spec.overflow_mode == "wrap":
-        return jnp.concatenate([norm[..., :L - 1], wrapped_top[..., None]], axis=-1)
-    # saturate: detect overflow (top limb outside signed top_bits range)
-    lo, hi = -(1 << (top_bits - 1)), (1 << (top_bits - 1)) - 1
-    over = top > hi
-    under = top < lo
-    sat_hi = jnp.full(norm.shape[:-1] + (L,), LIMB_MASK, jnp.int32)
-    sat_hi = sat_hi.at[..., L - 1].set(hi)
-    sat_lo = jnp.zeros(norm.shape[:-1] + (L,), jnp.int32)
-    sat_lo = sat_lo.at[..., L - 1].set(lo)
-    base = jnp.concatenate([norm[..., :L - 1],
-                            jnp.clip(top, lo, hi)[..., None]], axis=-1)
-    base = jnp.where(over[..., None], sat_hi, base)
-    base = jnp.where(under[..., None], sat_lo, base)
-    return base
-
-
-def add(spec: AccumulatorSpec, acc: Array, contributions: Array) -> Array:
-    """Exact add of limb contributions (no normalization)."""
+def carry_normalize(spec: AccumulatorSpec, limbs: Array) -> Array:
+    """``normalize_planes`` over a (..., L) array."""
     del spec
-    return acc + contributions
+    return _stack(normalize_planes(_unstack(limbs)))
+
+
+def _overflow_planes(spec: AccumulatorSpec, planes: Planes) -> list:
+    """Wrap or saturate a carry-normalized register at W bits (two's
+    complement)."""
+    L, W = spec.num_limbs, spec.width
+    top = planes[L - 1]
+    top_bits = W - LIMB_BITS * (L - 1)              # 1..16 significant top bits
+    if spec.overflow_mode == "wrap":
+        # sign-extend the top limb from top_bits
+        shift = 32 - top_bits
+        return list(planes[:L - 1]) + [
+            jnp.right_shift(jnp.left_shift(top, shift), shift)]
+    lo, hi = -(1 << (top_bits - 1)), (1 << (top_bits - 1)) - 1
+    over, under = top > hi, top < lo
+    low = [jnp.where(over, LIMB_MASK, jnp.where(under, 0, p))
+           for p in planes[:L - 1]]
+    return low + [jnp.clip(top, lo, hi)]
 
 
 def merge_states(spec: AccumulatorSpec, states: Array, axis: int = 0) -> Array:
@@ -357,22 +308,29 @@ def merge_states(spec: AccumulatorSpec, states: Array, axis: int = 0) -> Array:
     return carry_normalize(spec, jnp.sum(states, axis=axis))
 
 
-def to_float(spec: AccumulatorSpec, limbs: Array, out_precision: int = 24) -> Array:
-    """Round the accumulator ONCE to a float (RNE at ``out_precision`` bits)
-    and return f32. ``limbs`` must be carry-normalized. Exact for
-    out_precision <= 24."""
-    L = spec.num_limbs
-    limbs = finalize(spec, limbs)
-    sign_neg = limbs[..., L - 1] < 0
-    # magnitude digits: conditional two's-complement negate across limbs
-    mag = _negate_where(limbs, sign_neg)
-    # position of highest set bit
-    any_nz = jnp.any(mag != 0, axis=-1)
-    top_idx = jnp.zeros(mag.shape[:-1], jnp.int32)
-    for l in range(L):
-        top_idx = jnp.where(mag[..., l] != 0, l, top_idx)
-    top_val = jnp.take_along_axis(mag, top_idx[..., None], axis=-1)[..., 0]
-    hb = _ilog2(jnp.maximum(top_val, 1)) + top_idx * LIMB_BITS  # highest bit pos
+def _magnitude(spec: AccumulatorSpec, planes: Planes) -> tuple:
+    """Finalized register -> (is_negative, magnitude digits, any nonzero,
+    position of the highest set bit)."""
+    planes = _overflow_planes(spec, planes)
+    sign_neg = planes[-1] < 0
+    mag = _negate_where(planes, sign_neg)
+    any_nz = jnp.zeros(sign_neg.shape, jnp.bool_)
+    top_idx = jnp.zeros(sign_neg.shape, jnp.int32)
+    top_val = jnp.zeros(sign_neg.shape, jnp.int32)
+    for l, m in enumerate(mag):
+        nz = m != 0
+        any_nz = any_nz | nz
+        top_idx = jnp.where(nz, l, top_idx)
+        top_val = jnp.where(nz, m, top_val)
+    hb = _ilog2(jnp.maximum(top_val, 1)) + top_idx * LIMB_BITS
+    return sign_neg, mag, any_nz, hb
+
+
+def planes_to_float(spec: AccumulatorSpec, planes: Planes,
+                    out_precision: int = 24) -> Array:
+    """Round a carry-normalized register ONCE to a float (RNE at
+    ``out_precision`` bits) and return f32. Exact for out_precision <= 24."""
+    sign_neg, mag, any_nz, hb = _magnitude(spec, planes)
     # extract out_precision bits [hb-p+1 .. hb], guard at hb-p, sticky below
     p = out_precision
     take_from = hb - p + 1                                      # may be < 0
@@ -390,20 +348,16 @@ def to_float(spec: AccumulatorSpec, limbs: Array, out_precision: int = 24) -> Ar
     return jnp.where(any_nz, v, jnp.float32(0.0))
 
 
+def to_float(spec: AccumulatorSpec, limbs: Array, out_precision: int = 24) -> Array:
+    """``planes_to_float`` over a carry-normalized (..., L) array."""
+    return planes_to_float(spec, _unstack(limbs), out_precision)
+
+
 def to_float64(spec: AccumulatorSpec, limbs: Array) -> Array:
     """Round the accumulator ONCE to float64 (53-bit RNE). Requires x64 to be
     enabled (benchmark processes); the mantissa is assembled from two int32
     pieces so the limb algebra itself stays int32/TPU-shaped."""
-    L = spec.num_limbs
-    limbs = finalize(spec, limbs)
-    sign_neg = limbs[..., L - 1] < 0
-    mag = _negate_where(limbs, sign_neg)
-    any_nz = jnp.any(mag != 0, axis=-1)
-    top_idx = jnp.zeros(mag.shape[:-1], jnp.int32)
-    for l in range(L):
-        top_idx = jnp.where(mag[..., l] != 0, l, top_idx)
-    top_val = jnp.take_along_axis(mag, top_idx[..., None], axis=-1)[..., 0]
-    hb = _ilog2(jnp.maximum(top_val, 1)) + top_idx * LIMB_BITS
+    sign_neg, mag, any_nz, hb = _magnitude(spec, _unstack(limbs))
     p = 53
     take_from = hb - p + 1
     lo_bits = 29
@@ -419,41 +373,30 @@ def to_float64(spec: AccumulatorSpec, limbs: Array) -> Array:
     return jnp.where(any_nz, v, jnp.float64(0.0))
 
 
-def _negate_where(limbs: Array, cond: Array) -> Array:
+def _negate_where(planes: Planes, cond: Array) -> list:
     """Two's-complement negate across base-2^16 limbs where ``cond``.
 
     Input must be carry-normalized (digits 0..L-2 in [0,2^16), top limb a
     small signed value). Output where cond: magnitude digits, all in
     [0, 2^16)."""
-    L = limbs.shape[-1]
     out = []
-    borrow = jnp.zeros(limbs.shape[:-1], jnp.int32)
-    for l in range(L):
-        t = -limbs[..., l] - borrow
+    borrow = 0
+    for p in planes:
+        t = -p - borrow
         neg = (t < 0).astype(jnp.int32)
-        t = t + neg * (1 << LIMB_BITS)
+        out.append(jnp.where(cond, t + neg * (1 << LIMB_BITS), p))
         borrow = neg
-        out.append(t)
-    negated = jnp.stack(out, axis=-1)
-    return jnp.where(cond[..., None], negated, limbs)
+    return out
 
 
-def _canon(limbs: Array) -> Array:
-    """Canonicalize a normalized non-negative register to digits in [0,2^16).
-    (After carry_normalize, limbs 0..L-2 already are; the top limb of a
-    non-negative value is >= 0 and < 2^16 by width.)"""
-    return limbs
-
-
-def _extract_bits(mag: Array, start: Array, nbits: int) -> Array:
+def _extract_bits(mag: Planes, start: Array, nbits: int) -> Array:
     """Bits [start, start+nbits) of the magnitude register as int32.
-    start may be negative (those bits read as 0). nbits <= 24."""
+    start may be negative (those bits read as 0). nbits <= 29."""
     # value >> start, truncated to nbits: gathered from 3 adjacent limbs.
-    j = jnp.floor_divide(start, LIMB_BITS)
-    s = start - j * LIMB_BITS                     # 0..15
+    j, s = _limb_split(start)
     part0 = jnp.right_shift(_limb_at(mag, j), s)
     part1 = jnp.left_shift(_limb_at(mag, j + 1), LIMB_BITS - s)
-    # part2 only matters when s > 8 (bits 32-s .. < 24); clamp the shift.
+    # part2 only matters when s > 2*16 - nbits; clamp the shift.
     sh2 = jnp.clip(2 * LIMB_BITS - s, 0, 31)
     part2 = jnp.where(s > 2 * LIMB_BITS - nbits,
                       jnp.left_shift(_limb_at(mag, j + 2), sh2), 0)
@@ -461,34 +404,21 @@ def _extract_bits(mag: Array, start: Array, nbits: int) -> Array:
     return res & ((1 << nbits) - 1)
 
 
-def _limb_at(mag: Array, idx: Array) -> Array:
-    L = mag.shape[-1]
-    out = jnp.zeros(mag.shape[:-1], jnp.int32)
-    for l in range(L):
-        out = out + jnp.where(idx == l, mag[..., l], 0)
-    return jnp.where((idx >= 0) & (idx < L), out, 0)
+def _limb_at(mag: Planes, idx: Array) -> Array:
+    """Digit ``idx`` of the register (0 outside [0, L)), as a select chain —
+    no gather, so it lowers inside a kernel."""
+    out = jnp.zeros(idx.shape, jnp.int32)
+    for l, m in enumerate(mag):
+        out = jnp.where(idx == l, m, out)
+    return out
 
 
-def _any_below(mag: Array, below: Array) -> Array:
-    """True where any magnitude bit strictly below position ``below``+1 is set
-    — i.e. bits [0, below] inclusive... (sticky for positions < take_from-? )
-    Concretely: OR of bits at positions <= below."""
-    L = mag.shape[-1]
-    any_set = jnp.zeros(mag.shape[:-1], jnp.bool_)
-    for l in range(L):
+def _any_below(mag: Planes, below: Array) -> Array:
+    """OR of the magnitude bits at positions <= ``below`` (the sticky bit)."""
+    any_set = jnp.zeros(below.shape, jnp.bool_)
+    for l, m in enumerate(mag):
         lo = below + 1 - l * LIMB_BITS            # #bits of limb l at pos <= below
         nbits = jnp.clip(lo, 0, LIMB_BITS)
         mask = jnp.left_shift(1, nbits) - 1
-        any_set = any_set | ((mag[..., l] & mask) != 0)
+        any_set = any_set | ((m & mask) != 0)
     return any_set
-
-
-def value_as_float2(spec: AccumulatorSpec, limbs: Array) -> tuple[Array, Array]:
-    """Lossier helper: accumulator value as a head+tail f32 pair (for quick
-    diagnostics; NOT used in correctness paths)."""
-    L = spec.num_limbs
-    scale = [jnp.float32(2.0) ** (spec.lsb + LIMB_BITS * l) for l in range(L)]
-    hi = jnp.zeros(limbs.shape[:-1], jnp.float32)
-    for l in reversed(range(L)):
-        hi = hi + limbs[..., l].astype(jnp.float32) * scale[l]
-    return hi, jnp.zeros_like(hi)

@@ -379,17 +379,38 @@ class GemmPlan:
         return (self.bm, self.bn, self.bk)
 
     def fit(self, m: int, n: int, k: int) -> "GemmPlan":
-        """Clamp this plan to one problem instance: blocks stop at the
-        (8-aligned) problem dims and bk at the SAFE_CHUNK carry-headroom
-        bound. The ONE place a deployable schedule is constructed — the
-        kernel wrappers, the autotuner, and the persisted zoo all fit
-        through here, so half-legal schedules cannot exist."""
-        bm = min(self.bm, _ceil8(m))
-        bn = min(self.bn, _ceil8(n))
-        bk = min(min(self.bk, SAFE_CHUNK), _ceil8(k))
+        """Clamp this plan to one problem instance under the TPU tiling
+        rule, and bk to the SAFE_CHUNK carry-headroom bound. The ONE place
+        a deployable schedule is constructed — the kernel wrappers, the
+        autotuner, and the persisted zoo all fit through here, so illegal
+        schedules cannot exist.
+
+        The kernels' blocks are A (bm, bk), B (bk, bn) and O (bm, bn): bm
+        only ever sits on sublanes, bn and bk also sit on lanes. So bm is a
+        multiple of 8, and bn and bk are multiples of 128 — each block dim
+        stopping at the problem dim rounded up to 8, which the wrappers pad
+        to, so a clamped block is the whole padded array dim."""
+        bm = _fit_dim(self.bm, m, SUBLANES)
+        bn = _fit_dim(self.bn, n, LANES)
+        bk = _fit_dim(min(self.bk, SAFE_CHUNK), k, LANES)
         if (bm, bn, bk) == (self.bm, self.bn, self.bk):
             return self
         return dataclasses.replace(self, bm=bm, bn=bn, bk=bk)
+
+
+# The TPU's (sublane, lane) vector tile for 32-bit data: a block's last two
+# dims must be multiples of these, or equal to the array's dims.
+SUBLANES, LANES = 8, 128
+
+
+def _fit_dim(block: int, dim: int, align: int) -> int:
+    """One block dim under the tiling rule: the whole dim rounded up to 8
+    when the block covers it, else the block rounded down to ``align``
+    (at least ``align``)."""
+    full = _ceil8(dim)
+    if block >= full:
+        return full
+    return min(full, max(align, block - block % align))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -432,10 +453,10 @@ _PLAN_SIZE = _obs_registry().gauge(
 def _plan_stats_inc(op: str, n: int = 1) -> None:
     _PLAN_OPS.inc(n, op=op)
 
-# Candidate tiles for the measured path (clamped to the problem size).
+# Candidate tiles for the measured path (fitted to the problem size).
 AUTOTUNE_CANDIDATES = (
-    (32, 32, 128), (32, 32, 512), (64, 64, 256), (64, 64, 512),
-    (128, 128, 512), (128, 128, 1024), (8, 128, 512),
+    (8, 128, 512), (32, 128, 512), (128, 128, 512), (128, 128, 1024),
+    (128, 256, 512), (256, 256, 512),
 )
 
 
@@ -444,14 +465,11 @@ def _ceil8(x: int) -> int:
 
 
 def _heuristic_plan(batch: int, m: int, n: int, k: int) -> GemmPlan:
-    """Shape-derived default tile (the measured tables on this container put
-    the knee at 64..128 square output tiles with the deepest legal K block):
-    large bk amortizes the once-per-block carry normalization, and the M/N
-    blocks stop at the problem size so padding work stays bounded."""
-    bm = min(128, _ceil8(m))
-    bn = min(128, _ceil8(n))
-    bk = min(1024, min(SAFE_CHUNK, _ceil8(k)))
-    return GemmPlan(bm, bn, bk, source="heuristic")
+    """Shape-derived default tile: 128x128 output tiles with a deep K block
+    (large bk amortizes the once-per-block carry normalization), fitted so
+    the M/N blocks stop at the problem size and padding work stays bounded."""
+    del batch
+    return GemmPlan(128, 128, 1024, source="heuristic").fit(m, n, k)
 
 
 def _plan_key(batch, m, n, k, fmt, spec, backend):
@@ -571,10 +589,7 @@ def _measure_plan(m: int, n: int, k: int, *, fmt,
     for tile in sorted(cands):
         plan = GemmPlan(*tile)
         fn = lambda: kops.fdp_gemm(a, b, spec=spec, fmt=fmt, plan=plan)
-        try:
-            jax.block_until_ready(fn())          # compile + warm
-        except Exception:
-            continue
+        jax.block_until_ready(fn())              # compile + warm
         dt = _time_candidate(fn)
         if dt < best_t:
             best, best_t = tile, dt
@@ -921,13 +936,15 @@ def _segment_ids(group_sizes: Array, n_rows: int) -> Array:
 
 def _fit_ragged(plan: GemmPlan, axis: str, n_rows: int, n_groups: int
                 ) -> GemmPlan:
-    """Clamp the plan's token-axis block to the mean segment size (8-aligned).
+    """Clamp the plan's token-axis block to the mean segment size.
 
     The sorted-segment walk revisits one boundary tile per group, so its MAC
     count is ~(T + (E-1)·block)·d·f: a block sized for a dense GEMM (128)
     with many experts burns the entire O(T) advantage on boundary tiles.
     Blocking only changes the summation grouping — exact limb accumulation
-    keeps the result bit-identical for any clamp."""
+    keeps the result bit-identical for any clamp. The kernel wrappers fit
+    the result, so a token block that also sits on lanes (the wgrad's bk)
+    stays a multiple of 128."""
     block = min(getattr(plan, axis),
                 _ceil8(max(1, n_rows // max(1, n_groups))))
     if block == getattr(plan, axis):

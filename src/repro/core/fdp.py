@@ -98,13 +98,24 @@ def fdp_gemm_limbs(a: Array, b: Array, spec: AccumulatorSpec,
     < 2^16 after normalization; int32 headroom covers 2^13 of them — far more
     devices than any mesh).
     """
+    return jnp.stack(_gemm_planes(a, b, spec, fmt), axis=-1)
+
+
+# Bytes of one (kc, M, N) int32 slab of product contributions: XLA keeps a
+# few of them live per K chunk, so the chunk shrinks as M*N grows (a decode
+# step's lm_head is (slots x 151936)) and stays 512 deep for small GEMMs.
+_SLAB_BYTES = 32 << 20
+
+
+def _gemm_planes(a: Array, b: Array, spec: AccumulatorSpec, fmt) -> list:
+    """The carry-normalized register of a @ b as L (M, N) int32 planes."""
     assert a.ndim == 2 and b.ndim == 2 and a.shape[1] == b.shape[0]
     M, K = a.shape
     _, N = b.shape
     da, db = _decode(fmt, a), _decode(fmt, b)
 
     # chunk K to bound both memory and int32 carry headroom
-    kc = min(K, 512)
+    kc = max(1, min(K, 512, _SLAB_BYTES // (4 * M * N)))
     pad = (-K) % kc
     def padk(d, fill=0):
         return jax.tree.map(
@@ -118,21 +129,18 @@ def fdp_gemm_limbs(a: Array, b: Array, spec: AccumulatorSpec,
     da_c = jax.tree.map(lambda x: x.reshape(nchunks, kc, *x.shape[1:]), da_k)
     db_c = jax.tree.map(lambda x: x.reshape(nchunks, kc, *x.shape[1:]), db_k)
 
-    L = spec.num_limbs
-
-    def step(carry, chunk):
+    def step(planes, chunk):
         dac, dbc = chunk
         # broadcast to (kc, M, N): sign/mant/exp combine elementwise
-        def bc(d, which):
-            return jax.tree.map(
-                lambda x: x[:, :, None] if which == "a" else x[:, None, :], d)
-        s = carry + acc.product_limb_block_sum(
-            spec, bc(dac, "a"), bc(dbc, "b"), axis=0)      # limb-fused (M,N,L)
-        return acc.carry_normalize(spec, s), None
+        sums = acc.product_planes(
+            spec, jax.tree.map(lambda x: x[:, :, None], dac),
+            jax.tree.map(lambda x: x[:, None, :], dbc), reduce_leading=True)
+        return tuple(acc.normalize_planes(
+            [p + s for p, s in zip(planes, sums)])), None
 
-    init = jnp.zeros((M, N, L), jnp.int32)
-    out, _ = jax.lax.scan(step, init, (da_c, db_c))
-    return out
+    init = (jnp.zeros((M, N), jnp.int32),) * spec.num_limbs
+    planes, _ = jax.lax.scan(step, init, (da_c, db_c))
+    return list(planes)
 
 
 @partial(jax.jit, static_argnums=(2, 3))
@@ -146,7 +154,7 @@ def fdp_gemm(a: Array, b: Array, spec: AccumulatorSpec,
     computation stopped before the single read-out rounding — the partial-K
     state a sharded reduction merges across devices.
     """
-    return acc.to_float(spec, fdp_gemm_limbs(a, b, spec, fmt))
+    return acc.planes_to_float(spec, _gemm_planes(a, b, spec, fmt))
 
 
 def quantize_products(a: Array, b: Array, spec: AccumulatorSpec,

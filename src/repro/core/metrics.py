@@ -45,6 +45,39 @@ def fraction_to_float(f: Fraction) -> float:
     return float(f)
 
 
+def frac_to_f32_rne(f: Fraction) -> np.float32:
+    """Correct single RNE from Fraction to float32 (host oracle)."""
+    if f == 0:
+        return np.float32(0.0)
+    s = -1 if f < 0 else 1
+    f = abs(f)
+    e = f.numerator.bit_length() - f.denominator.bit_length() - 23
+    while f / Fraction(2) ** e >= 2 ** 24:
+        e += 1
+    while f / Fraction(2) ** e < 2 ** 23:
+        e -= 1
+    m = f / Fraction(2) ** e
+    mi = int(m)
+    rem = m - mi
+    if rem > Fraction(1, 2) or (rem == Fraction(1, 2) and mi % 2 == 1):
+        mi += 1
+    return np.float32(s * np.ldexp(np.float64(mi), e))
+
+
+def fdp_oracle(a, b, spec) -> np.float32:
+    """Host-side normative semantics: per-product trunc at 2^lsb, exact sum,
+    W-bit wrap, single RNE to f32."""
+    exact = Fraction(0)
+    scale = Fraction(2) ** spec.lsb
+    for x, y in zip(np.asarray(a, np.float64).tolist(),
+                    np.asarray(b, np.float64).tolist()):
+        p = Fraction(x) * Fraction(y)
+        exact += int(abs(p) / scale) * (1 if p >= 0 else -1)
+    W = spec.width
+    wrapped = ((int(exact) + 2 ** (W - 1)) % 2 ** W) - 2 ** (W - 1)
+    return frac_to_f32_rne(Fraction(wrapped) * scale)
+
+
 def reproducibility_deviation(fn, a, b, n_orders: int = 8, seed: int = 0):
     """Max absolute deviation of fn(a,b) across random input permutations —
     the paper's reproducibility probe (0.0 for the FDP by construction)."""

@@ -4,23 +4,28 @@ TPU adaptation of the paper's FPGA systolic GEMM (FCCM'22): the MXU cannot be
 re-wired, so the exact accumulator lives in **VMEM scratch as int32 limbs** and
 the per-product decode/align/accumulate micro-ops run on the VPU. Tiling is
 classic Pallas matmul: grid (M/bm, N/bn, K/bk) with K innermost; the limb
-register (bm, bn, L) persists in scratch across the K grid dimension and is
-rounded to f32 once, on the last K step — "never round between accumulations".
+register persists in scratch across the K grid dimension and is rounded to
+f32 once, on the last K step — "never round between accumulations".
 
-The hot path is *limb-vectorized*: all ``bk`` product contributions of a K
-block are computed as one ``(kc, bm, bn, L)`` tensor op per K sub-chunk (no
-per-k scalar loop), summed exactly in int32, and carry-normalized ONCE per K
-block. A batched variant runs ``(B, M, K) @ (B, K, N)`` as a single
-``pallas_call`` over a 4-D grid instead of a vmap of the 2-D kernel.
+Layout: the register is limb-leading, ``(L, bm, bn)`` int32, so each limb is
+a plane of whole (8, 128) vector tiles (a limb-minor ``(bm, bn, L)`` register
+pads L to 128 lanes and its per-limb slices do not lower). Each grid step
+writes its A tile K-major into a ``(bk, bm)`` scratch, then a ``fori_loop``
+walks K in sub-chunks of ``kc`` rows: both operands' rows are decoded, their
+``(kc, bm, bn)`` product contributions are summed limb by limb into the
+planes (``accumulator.product_planes``), and the register is
+carry-normalized ONCE per K block. A batched variant runs
+``(B, M, K) @ (B, K, N)`` as a single ``pallas_call`` over a 4-D grid.
 
 Int32 carry discipline: each product contributes < 2^17 per limb, so a K block
 of ``bk <= SAFE_CHUNK`` (= 2^13) products is safe between carry
 normalizations; the bound is derived in ``repro.core.accumulator`` and
 enforced here via ``MAX_BK`` (callers: ops.py).
 
-Block sizes are chosen MXU/VPU-aligned (multiples of 8×128 lanes); the kernel
-is validated bit-exactly against the pure-jnp oracle (ref.py) in interpret
-mode, which executes this same body on CPU.
+Block shapes follow the TPU tiling rule that ``GemmPlan.fit`` enforces (each
+block dim a multiple of 128, or the whole padded dim, which is a multiple of
+8). The kernels are validated bit-exactly against the pure-jnp oracle
+(``repro.core.fdp``) in interpret mode, which executes this same body on CPU.
 """
 
 from __future__ import annotations
@@ -30,6 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import accumulator as acc
 from repro.core.accumulator import SAFE_CHUNK, AccumulatorSpec
@@ -38,37 +44,76 @@ from repro.core.accumulator import SAFE_CHUNK, AccumulatorSpec
 # accumulate at most SAFE_CHUNK products between carry normalizations.
 MAX_BK = SAFE_CHUNK
 
-# Slab memory budget for the vectorized inner op, per K sub-chunk. The fused
-# limb reduction (product_limb_block_sum) keeps ~a dozen (kc, bm, bn) int32
-# temporaries live, never a (kc, bm, bn, L) tensor, so the budget is per
-# single slab. Interpret mode runs through XLA:CPU where the sweet spot is
-# L2/L3-cache-sized slabs; on a real TPU the temporaries must share ~16 MB of
-# VMEM with the operand blocks.
+# Bytes of one (kc, bm, bn) int32 slab per K sub-chunk. The planes reduction
+# keeps about a dozen such temporaries live. Interpret mode runs through
+# XLA:CPU, where cache-sized slabs and few loop trips win; on the TPU the
+# temporaries share VMEM with the operand tiles and the register.
 _SLAB_BYTES_INTERPRET = 16 << 20
-_SLAB_BYTES_TPU = 128 << 10
-_MAX_K_SUBCHUNKS = 16            # unroll cap for the static sub-chunk loop
+_SLAB_BYTES_TPU = 64 << 10
 
 
-def _k_subchunk(bm: int, bn: int, bk: int, num_limbs: int,
-                interpret: bool) -> int:
-    """Pick the K sub-chunk size kc: as large as the slab budget allows so
-    each (kc, bm, bn) slab stays one vector op, but capped so the static
-    sub-chunk loop unrolls at most _MAX_K_SUBCHUNKS times."""
-    del num_limbs  # the fused reduction's slabs are L-independent
+def _k_subchunk(bm: int, bn: int, bk: int, interpret: bool) -> int:
+    """K rows per loop step: the largest power of two that divides ``bk``
+    and keeps one (kc, bm, bn) int32 slab within the backend's budget."""
     budget = _SLAB_BYTES_INTERPRET if interpret else _SLAB_BYTES_TPU
-    per_k = bm * bn * 4
-    kc = max(1, budget // per_k)
-    kc = max(kc, -(-bk // _MAX_K_SUBCHUNKS))
-    return min(kc, bk)
+    rows = max(1, budget // (bm * bn * 4))
+    kc = 1
+    while kc * 2 <= min(rows, bk) and bk % (kc * 2) == 0:
+        kc *= 2
+    return kc
 
 
-def fdp_gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, spec: AccumulatorSpec,
-                    fmt, bk: int, k_grid: int, kc: int, batched: bool):
-    """Vectorized kernel body (2-D and batched grids).
+def _carrier(x: jax.Array) -> jax.Array:
+    """Float operands travel as f32 (exact for every format the decoder
+    reads); posit bit patterns stay int32. One 32-bit tile layout for all."""
+    return x.astype(jnp.float32) if jnp.issubdtype(x.dtype, jnp.floating) else x
+
+
+def _scratch(bm: int, bn: int, bk: int, spec: AccumulatorSpec, dtype):
+    """Limb register (L, bm, bn) int32 + the K-major A tile (bk, bm)."""
+    return [pltpu.VMEM((spec.num_limbs, bm, bn), jnp.int32),
+            pltpu.VMEM((bk, bm), dtype)]
+
+
+def _accumulate(acc_ref, at_ref, load_b, *, spec: AccumulatorSpec, fmt,
+                kc: int):
+    """acc (L, bm, bn) += at (bk, bm)ᵀ · b (bk, bn), exactly.
+
+    ``load_b(k0, kc)`` returns B's rows [k0, k0+kc) as a (kc, bn) array. The
+    sum of a K block's bk <= SAFE_CHUNK products fits the int32 headroom, so
+    the register is carry-normalized once, after the loop."""
+    L = spec.num_limbs
+    bk = at_ref.shape[0]
+
+    def step(c, planes):
+        k0 = pl.multiple_of(c * kc, kc)
+        da = fmt.decode(at_ref[pl.ds(k0, kc), :])                 # (kc, bm)
+        db = fmt.decode(load_b(k0, kc))                           # (kc, bn)
+        da = jax.tree.map(lambda x: x[:, :, None], da)            # (kc, bm, 1)
+        db = jax.tree.map(lambda x: x[:, None, :], db)            # (kc, 1, bn)
+        sums = acc.product_planes(spec, da, db, reduce_leading=True)
+        return tuple(p + s for p, s in zip(planes, sums))
+
+    planes = jax.lax.fori_loop(0, bk // kc, step,
+                               tuple(acc_ref[l] for l in range(L)))
+    for l, p in enumerate(acc.normalize_planes(planes)):
+        acc_ref[l] = p
+
+
+def _read_out(spec: AccumulatorSpec, acc_ref) -> jax.Array:
+    """The register rounded once to f32, (bm, bn)."""
+    return acc.planes_to_float(
+        spec, [acc_ref[l] for l in range(spec.num_limbs)])
+
+
+def fdp_gemm_kernel(a_ref, b_ref, o_ref, acc_ref, at_ref, *,
+                    spec: AccumulatorSpec, fmt, k_grid: int, kc: int,
+                    batched: bool):
+    """Kernel body (2-D and batched grids).
 
     2-D:     a (bm, bk), b (bk, bn), o (bm, bn) f32, grid (Mg, Ng, Kg).
     batched: a (1, bm, bk), b (1, bk, bn), o (1, bm, bn), grid (B, Mg, Ng, Kg).
-    acc scratch: (bm, bn, L) int32, persists across the (innermost) K axis.
+    acc scratch: (L, bm, bn) int32, persists across the (innermost) K axis.
     """
     kidx = pl.program_id(3 if batched else 2)
 
@@ -76,76 +121,25 @@ def fdp_gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, spec: AccumulatorSpec,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...]
-    b = b_ref[...]
+    at_ref[...] = (a_ref[0] if batched else a_ref[...]).T
     if batched:
-        a, b = a[0], b[0]
-    da = fmt.decode(a)                                 # fields (bm, bk)
-    db = fmt.decode(b)                                 # fields (bk, bn)
-    da = jax.tree.map(lambda x: x.T, da)               # fields (bk, bm)
-
-    # All bk contributions of this K block, reduced limb-by-limb over
-    # (kc, bm, bn) slabs (never materializing a (kc, bm, bn, L) tensor);
-    # one carry normalization per K block (bk <= SAFE_CHUNK).
-    total = acc_ref[...]
-    for k0 in range(0, bk, kc):
-        dak = jax.tree.map(lambda x: x[k0:k0 + kc, :, None], da)   # (kc, bm, 1)
-        dbk = jax.tree.map(lambda x: x[k0:k0 + kc, None, :], db)   # (kc, 1, bn)
-        total = total + acc.product_limb_block_sum(spec, dak, dbk, axis=0)
-    acc_ref[...] = acc.carry_normalize(spec, total)
+        load_b = lambda k0, n: b_ref[0, pl.ds(k0, n), :]
+    else:
+        load_b = lambda k0, n: b_ref[pl.ds(k0, n), :]
+    _accumulate(acc_ref, at_ref, load_b, spec=spec, fmt=fmt, kc=kc)
 
     @pl.when(kidx == k_grid - 1)
     def _emit():
-        out = acc.to_float(spec, acc_ref[...])
+        out = _read_out(spec, acc_ref)
         o_ref[...] = out[None] if batched else out
-
-
-def fdp_gemm_kernel_looped(a_ref, b_ref, o_ref, acc_ref, *,
-                           spec: AccumulatorSpec, fmt, bk: int, k_grid: int):
-    """The seed per-k scalar loop body, kept as the benchmark baseline
-    (benchmarks/bench_gemm.py measures the vectorized kernel against it)."""
-    kidx = pl.program_id(2)
-
-    @pl.when(kidx == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    a = a_ref[...]
-    b = b_ref[...]
-    da = fmt.decode(a)          # fields (bm, bk)
-    db = fmt.decode(b)          # fields (bk, bn)
-
-    def body(k, limbs):
-        dak = jax.tree.map(lambda x: jax.lax.dynamic_slice_in_dim(x, k, 1, 1)[:, 0], da)
-        dbk = jax.tree.map(lambda x: jax.lax.dynamic_slice_in_dim(x, k, 1, 0)[0, :], db)
-        dak = jax.tree.map(lambda x: x[:, None], dak)     # (bm, 1)
-        dbk = jax.tree.map(lambda x: x[None, :], dbk)     # (1, bn)
-        contrib = acc.product_limbs(spec, dak, dbk)       # (bm, bn, L)
-        return limbs + contrib
-
-    limbs = jax.lax.fori_loop(0, bk, body, acc_ref[...])
-    limbs = acc.carry_normalize(spec, limbs)              # once per K block
-    acc_ref[...] = limbs
-
-    @pl.when(kidx == k_grid - 1)
-    def _emit():
-        o_ref[...] = acc.to_float(spec, acc_ref[...])
-
-
-def _scratch(bm: int, bn: int, L: int):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return [pltpu.VMEM((bm, bn, L), jnp.int32)]
-    except Exception:  # pragma: no cover
-        return [pl.MemorySpace.ANY((bm, bn, L), jnp.int32)]
 
 
 def fdp_gemm_pallas(a: jax.Array, b: jax.Array, *, spec: AccumulatorSpec,
                     fmt, bm: int = 128, bn: int = 128, bk: int = 512,
-                    interpret: bool = True, impl: str = "vector") -> jax.Array:
+                    interpret: bool = True) -> jax.Array:
     """Raw pallas_call wrapper; shapes must be multiples of the block sizes
-    (ops.py pads). Inputs: f32/bf16 arrays, or int32 posit patterns.
-    ``impl``: "vector" (default hot path) or "loop" (seed baseline)."""
+    (ops.py pads). Inputs: float arrays, or int32 posit patterns."""
+    a, b = _carrier(a), _carrier(b)
     M, K = a.shape
     K2, N = b.shape
     assert K == K2
@@ -154,20 +148,10 @@ def fdp_gemm_pallas(a: jax.Array, b: jax.Array, *, spec: AccumulatorSpec,
         f"bk={bk} exceeds SAFE_CHUNK={SAFE_CHUNK} (= 2^13): int32 limbs take "
         f"< 2^17 per product, so at most SAFE_CHUNK products may accumulate "
         f"between carry normalizations")
-    L = spec.num_limbs
     grid = (M // bm, N // bn, K // bk)
-
-    if impl == "vector":
-        kc = _k_subchunk(bm, bn, bk, L, interpret)
-        kernel = functools.partial(
-            fdp_gemm_kernel, spec=spec, fmt=fmt, bk=bk, k_grid=grid[2],
-            kc=kc, batched=False)
-    elif impl == "loop":
-        kernel = functools.partial(
-            fdp_gemm_kernel_looped, spec=spec, fmt=fmt, bk=bk, k_grid=grid[2])
-    else:
-        raise ValueError(f"unknown impl {impl!r}")
-
+    kernel = functools.partial(
+        fdp_gemm_kernel, spec=spec, fmt=fmt, k_grid=grid[2],
+        kc=_k_subchunk(bm, bn, bk, interpret), batched=False)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -177,8 +161,9 @@ def fdp_gemm_pallas(a: jax.Array, b: jax.Array, *, spec: AccumulatorSpec,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        scratch_shapes=_scratch(bm, bn, L),
+        scratch_shapes=_scratch(bm, bn, bk, spec, a.dtype),
         interpret=interpret,
+        name="fdp_gemm",
     )(a, b)
 
 
@@ -267,21 +252,19 @@ def _ragged_meta(group_sizes: jax.Array, n_rows: int, block: int, *,
                       first.astype(jnp.int32), last.astype(jnp.int32)])
 
 
-def _masked_rows(ref, block_idx, block: int, lo, hi):
-    """Zero rows of a (block, ...) operand tile outside its segment's global
-    [lo, hi) window. Exact for every format: 0.0 is the zero float carrier
-    and 0 the zero posit pattern, and zero products add nothing to the limb
-    register."""
-    rows = block_idx * block + jax.lax.broadcasted_iota(
-        jnp.int32, (block, 1), 0)
-    mask = (rows >= lo) & (rows < hi)
-    x = ref[...]
-    return jnp.where(mask, x, jnp.zeros((), x.dtype))
+def _segment_mask(x, block_idx, block: int, lo, hi, axis: int):
+    """Zero the token rows (``axis`` 0) or token columns (``axis`` 1) of an
+    operand tile outside its segment's global [lo, hi) window. Exact for
+    every format: 0.0 is the zero float carrier and 0 the zero posit
+    pattern, and zero products add nothing to the limb register."""
+    shape = (block, 1) if axis == 0 else (1, block)
+    tok = block_idx * block + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    return jnp.where((tok >= lo) & (tok < hi), x, jnp.zeros((), x.dtype))
 
 
-def fdp_ragged_kernel(meta_ref, x_ref, w_ref, o_ref, acc_ref, *,
-                      spec: AccumulatorSpec, fmt, bm: int, bk: int,
-                      k_grid: int, kc: int):
+def fdp_ragged_kernel(meta_ref, x_ref, w_ref, o_ref, acc_ref, at_ref, *,
+                      spec: AccumulatorSpec, fmt, bm: int, k_grid: int,
+                      kc: int):
     """Sorted-segment forward body. Grid (Ng, NT, Kg), K innermost:
     x (bm, bk) at (block[t], k), w (1, bk, bn) at (group[t], k, j),
     o (bm, bn) at (block[t], j). The limb scratch spans all tiles of one
@@ -299,47 +282,35 @@ def fdp_ragged_kernel(meta_ref, x_ref, w_ref, o_ref, acc_ref, *,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = _masked_rows(x_ref, tm, bm, lo, hi)            # (bm, bk)
-    da = fmt.decode(x)                                 # fields (bm, bk)
-    db = fmt.decode(w_ref[0])                          # fields (bk, bn)
-    da = jax.tree.map(lambda v: v.T, da)               # fields (bk, bm)
-
-    total = acc_ref[...]
-    for k0 in range(0, bk, kc):
-        dak = jax.tree.map(lambda v: v[k0:k0 + kc, :, None], da)
-        dbk = jax.tree.map(lambda v: v[k0:k0 + kc, None, :], db)
-        total = total + acc.product_limb_block_sum(spec, dak, dbk, axis=0)
-    acc_ref[...] = acc.carry_normalize(spec, total)
+    at_ref[...] = _segment_mask(x_ref[...], tm, bm, lo, hi, axis=0).T
+    _accumulate(acc_ref, at_ref, lambda k0, n: w_ref[0, pl.ds(k0, n), :],
+                spec=spec, fmt=fmt, kc=kc)
 
     @pl.when((last == 1) & (kidx == k_grid - 1))
     def _emit():
-        o_ref[...] = acc.to_float(spec, acc_ref[...])
+        o_ref[...] = _read_out(spec, acc_ref)
 
 
 def fdp_ragged_gemm_pallas(x: jax.Array, w: jax.Array,
                            group_sizes: jax.Array, *, spec: AccumulatorSpec,
-                           fmt, bm: int = 32, bn: int = 32, bk: int = 128,
+                           fmt, bm: int = 128, bn: int = 128, bk: int = 512,
                            interpret: bool = True) -> jax.Array:
     """Raw sorted-segment grouped GEMM: x (T, d) @ w[group(t)] -> (T, f).
     T/d/f must be block multiples (ops.py pads); rows beyond
     sum(group_sizes) yield zeros."""
-    from jax.experimental.pallas import tpu as pltpu
-
+    x, w = _carrier(x), _carrier(w)
     T, d = x.shape
     E, d2, f = w.shape
     assert d == d2, (x.shape, w.shape)
     assert T % bm == 0 and f % bn == 0 and d % bk == 0, (T, d, f, bm, bn, bk)
     assert bk <= MAX_BK, (
         f"bk={bk} exceeds SAFE_CHUNK={SAFE_CHUNK} carry headroom")
-    L = spec.num_limbs
     NT = ragged_num_tiles(T, bm, E)
     k_grid = d // bk
     meta = _ragged_meta(group_sizes, T, bm, cover_all_groups=False)
-    kc = _k_subchunk(bm, bn, bk, L, interpret)
-
     kernel = functools.partial(
-        fdp_ragged_kernel, spec=spec, fmt=fmt, bm=bm, bk=bk, k_grid=k_grid,
-        kc=kc)
+        fdp_ragged_kernel, spec=spec, fmt=fmt, bm=bm, k_grid=k_grid,
+        kc=_k_subchunk(bm, bn, bk, interpret))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(f // bn, NT, k_grid),
@@ -350,20 +321,21 @@ def fdp_ragged_gemm_pallas(x: jax.Array, w: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, bn),
                                lambda j, t, k, meta: (meta[0, t], j)),
-        scratch_shapes=_scratch(bm, bn, L),
+        scratch_shapes=_scratch(bm, bn, bk, spec, x.dtype),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, f), jnp.float32),
         interpret=interpret,
+        name="fdp_ragged_gemm",
     )(meta, x, w)
 
 
-def fdp_ragged_dw_kernel(meta_ref, x_ref, g_ref, o_ref, acc_ref, *,
+def fdp_ragged_dw_kernel(meta_ref, xt_ref, g_ref, o_ref, acc_ref, at_ref, *,
                          spec: AccumulatorSpec, fmt, bkt: int, kc: int):
     """Sorted-segment wgrad body. Grid (Mg, Ng, NT), tiles innermost:
-    x (bkt, bm) at (block[t], i), g (bkt, bn) at (block[t], j),
+    xᵀ (bm, bkt) at (i, block[t]), g (bkt, bn) at (block[t], j),
     o (1, bm, bn) at (group[t], i, j). The contraction dim is the ragged
     token dim; the limb scratch spans all tiles of one *group* (first/last
     markers are per group), so zero-size groups emit exact zeros from their
@@ -379,31 +351,23 @@ def fdp_ragged_dw_kernel(meta_ref, x_ref, g_ref, o_ref, acc_ref, *,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    xm = _masked_rows(x_ref, tb, bkt, lo, hi)          # (bkt, bm), k-major
-    da = fmt.decode(xm)
-    db = fmt.decode(g_ref[...])                        # fields (bkt, bn)
-
-    total = acc_ref[...]
-    for k0 in range(0, bkt, kc):
-        dak = jax.tree.map(lambda v: v[k0:k0 + kc, :, None], da)
-        dbk = jax.tree.map(lambda v: v[k0:k0 + kc, None, :], db)
-        total = total + acc.product_limb_block_sum(spec, dak, dbk, axis=0)
-    acc_ref[...] = acc.carry_normalize(spec, total)
+    at_ref[...] = _segment_mask(xt_ref[...], tb, bkt, lo, hi, axis=1).T
+    _accumulate(acc_ref, at_ref, lambda k0, n: g_ref[pl.ds(k0, n), :],
+                spec=spec, fmt=fmt, kc=kc)
 
     @pl.when(last == 1)
     def _emit():
-        o_ref[...] = acc.to_float(spec, acc_ref[...])[None]
+        o_ref[...] = _read_out(spec, acc_ref)[None]
 
 
 def fdp_ragged_dw_pallas(x: jax.Array, g: jax.Array, group_sizes: jax.Array,
-                         *, spec: AccumulatorSpec, fmt, bm: int = 32,
-                         bn: int = 32, bk: int = 128,
+                         *, spec: AccumulatorSpec, fmt, bm: int = 128,
+                         bn: int = 128, bk: int = 512,
                          interpret: bool = True) -> jax.Array:
     """Raw sorted-segment grouped weight gradient:
     dW[e] = x[rows of e]ᵀ @ g[rows of e] -> (E, d, f). ``bk`` blocks the
     ragged token dim (T must be a bk multiple; ops.py pads)."""
-    from jax.experimental.pallas import tpu as pltpu
-
+    x, g = _carrier(x), _carrier(g)
     T, d = x.shape
     T2, f = g.shape
     assert T == T2, (x.shape, g.shape)
@@ -411,30 +375,29 @@ def fdp_ragged_dw_pallas(x: jax.Array, g: jax.Array, group_sizes: jax.Array,
     assert T % bk == 0 and d % bm == 0 and f % bn == 0, (T, d, f, bm, bn, bk)
     assert bk <= MAX_BK, (
         f"bk={bk} exceeds SAFE_CHUNK={SAFE_CHUNK} carry headroom")
-    L = spec.num_limbs
     NT = ragged_num_tiles(T, bk, E)
     meta = _ragged_meta(group_sizes, T, bk, cover_all_groups=True)
-    kc = _k_subchunk(bm, bn, bk, L, interpret)
-
     kernel = functools.partial(
-        fdp_ragged_dw_kernel, spec=spec, fmt=fmt, bkt=bk, kc=kc)
+        fdp_ragged_dw_kernel, spec=spec, fmt=fmt, bkt=bk,
+        kc=_k_subchunk(bm, bn, bk, interpret))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(d // bm, f // bn, NT),
         in_specs=[
-            pl.BlockSpec((bk, bm), lambda i, j, t, meta: (meta[0, t], i)),
+            pl.BlockSpec((bm, bk), lambda i, j, t, meta: (i, meta[0, t])),
             pl.BlockSpec((bk, bn), lambda i, j, t, meta: (meta[0, t], j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn),
                                lambda i, j, t, meta: (meta[1, t], i, j)),
-        scratch_shapes=_scratch(bm, bn, L),
+        scratch_shapes=_scratch(bm, bn, bk, spec, x.dtype),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((E, d, f), jnp.float32),
         interpret=interpret,
-    )(meta, x, g)
+        name="fdp_ragged_dw",
+    )(meta, x.T, g)
 
 
 def fdp_gemm_pallas_batched(a: jax.Array, b: jax.Array, *,
@@ -445,20 +408,17 @@ def fdp_gemm_pallas_batched(a: jax.Array, b: jax.Array, *,
     pallas_call over grid (B, M/bm, N/bn, K/bk) — no vmap-of-kernel. The limb
     scratch persists across the innermost K axis only, so each (batch, i, j)
     tile accumulates independently."""
+    a, b = _carrier(a), _carrier(b)
     B, M, K = a.shape
     B2, K2, N = b.shape
     assert B == B2 and K == K2, (a.shape, b.shape)
     assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
     assert bk <= MAX_BK, (
         f"bk={bk} exceeds SAFE_CHUNK={SAFE_CHUNK} carry headroom")
-    L = spec.num_limbs
     grid = (B, M // bm, N // bn, K // bk)
-    kc = _k_subchunk(bm, bn, bk, L, interpret)
-
     kernel = functools.partial(
-        fdp_gemm_kernel, spec=spec, fmt=fmt, bk=bk, k_grid=grid[3],
-        kc=kc, batched=True)
-
+        fdp_gemm_kernel, spec=spec, fmt=fmt, k_grid=grid[3],
+        kc=_k_subchunk(bm, bn, bk, interpret), batched=True)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -468,6 +428,7 @@ def fdp_gemm_pallas_batched(a: jax.Array, b: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, M, N), jnp.float32),
-        scratch_shapes=_scratch(bm, bn, L),
+        scratch_shapes=_scratch(bm, bn, bk, spec, a.dtype),
         interpret=interpret,
+        name="fdp_gemm_batched",
     )(a, b)

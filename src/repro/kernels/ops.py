@@ -28,9 +28,8 @@ from repro.core.formats import FP32
 from .fdp_gemm import (MAX_BK, fdp_gemm_pallas, fdp_gemm_pallas_batched,
                        fdp_ragged_dw_pallas, fdp_ragged_gemm_pallas)
 
-# Default tile when a caller passes no plan (matches the historical
-# keyword defaults).
-_DEFAULT_TILE = (32, 32, 128)
+# Default tile when a caller passes no plan (fitted like any other).
+_DEFAULT_TILE = (128, 128, 512)
 
 
 def _on_tpu() -> bool:
@@ -46,8 +45,8 @@ def resolve_plan(plan, M: int, N: int, K: int) -> GemmPlan:
 
 
 @partial(jax.jit,
-         static_argnames=("spec", "fmt", "bm", "bn", "bk", "interpret", "impl"))
-def _fdp_gemm_jit(a, b, *, spec, fmt, bm, bn, bk, interpret, impl):
+         static_argnames=("spec", "fmt", "bm", "bn", "bk", "interpret"))
+def _fdp_gemm_jit(a, b, *, spec, fmt, bm, bn, bk, interpret):
     M, K = a.shape
     _, N = b.shape
     pm, pn, pk = (-M) % bm, (-N) % bn, (-K) % bk
@@ -57,19 +56,19 @@ def _fdp_gemm_jit(a, b, *, spec, fmt, bm, bn, bk, interpret, impl):
         b = jnp.pad(b, ((0, pk), (0, pn)))
     interp = (not _on_tpu()) if interpret is None else interpret
     out = fdp_gemm_pallas(a, b, spec=spec, fmt=fmt, bm=bm, bn=bn, bk=bk,
-                          interpret=interp, impl=impl)
+                          interpret=interp)
     return out[:M, :N]
 
 
 def fdp_gemm(a: jax.Array, b: jax.Array, *, spec: AccumulatorSpec, fmt=FP32,
-             plan: GemmPlan | None = None, interpret: bool | None = None,
-             impl: str = "vector") -> jax.Array:
+             plan: GemmPlan | None = None,
+             interpret: bool | None = None) -> jax.Array:
     """GEMM with tailored FDP accumulation: (M,K)@(K,N) -> (M,N) f32."""
     M, K = a.shape
     _, N = b.shape
     p = resolve_plan(plan, M, N, K)
     return _fdp_gemm_jit(a, b, spec=spec, fmt=fmt, bm=p.bm, bn=p.bn, bk=p.bk,
-                         interpret=interpret, impl=impl)
+                         interpret=interpret)
 
 
 @partial(jax.jit,
@@ -104,7 +103,9 @@ def fdp_gemm_batched(a: jax.Array, b: jax.Array, *, spec: AccumulatorSpec,
 def matmul_batching(f2d, f3d):
     """Wrap a 2-D kernel and a flat-batched 3-D kernel into one
     jnp.matmul-shaped callable: 1-D operands are promoted (and the result
-    squeezed back, down to a scalar for vector·vector), leading batch dims
+    squeezed back, down to a scalar for vector·vector), an N-D ``a`` times
+    a 2-D ``b`` (activations times a weight) folds its leading dims into M
+    so the weight is never broadcast, and otherwise leading batch dims
     broadcast numpy-style and flatten into the 3-D kernel's batch axis."""
     def call(a: jax.Array, b: jax.Array) -> jax.Array:
         squeeze_a = a.ndim == 1
@@ -113,8 +114,9 @@ def matmul_batching(f2d, f3d):
             a = a[None, :]
         if squeeze_b:
             b = b[:, None]
-        if a.ndim == 2 and b.ndim == 2:
-            out = f2d(a, b)
+        if b.ndim == 2:
+            out = f2d(a.reshape(-1, a.shape[-1]), b)
+            out = out.reshape(a.shape[:-1] + out.shape[-1:])
         else:
             batch = jnp.broadcast_shapes(a.shape[:-2], b.shape[:-2])
             a = jnp.broadcast_to(a, batch + a.shape[-2:])

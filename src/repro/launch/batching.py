@@ -116,9 +116,12 @@ class ContinuousBatcher:
         # guard for "warmup must compile under the serving policy"
         self.trace_count = 0
 
-        def _step_fn(c, t):
+        # the weights are an argument, not a closure: a closed-over array
+        # becomes a constant of the executable, and every (plan, bucket)
+        # engine of a pool would carry its own copy of the model
+        def _step_fn(p, c, t):
             self.trace_count += 1            # python side effect: trace-time only
-            return decode_step(params, cfg, c, t, dist)
+            return decode_step(p, cfg, c, t, dist)
 
         self._step = jax.jit(_step_fn)
         if warmup:
@@ -133,7 +136,8 @@ class ContinuousBatcher:
             # time. This is the ROADMAP "batching under plans" fix.
             tok0 = jnp.zeros((n_slots, 1), jnp.int32)
             with self._policy_ctx():
-                self._step = self._step.lower(self.cache, tok0).compile()
+                self._step = self._step.lower(params, self.cache,
+                                              tok0).compile()
 
     def _policy_ctx(self):
         return use_policy(self.policy) if self.policy is not None \
@@ -224,7 +228,7 @@ class ContinuousBatcher:
         # policy context here keeps that trace (and any retrace) under the
         # same numerics the warmup path compiles with
         with self._policy_ctx():
-            logits, self.cache = self._step(self.cache, toks)
+            logits, self.cache = self._step(self.params, self.cache, toks)
         self._len += 1
         nxt = np.asarray(jnp.argmax(logits[:, 0, :self.cfg.vocab_size], -1))
         for i, req in enumerate(self.active):

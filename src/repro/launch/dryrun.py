@@ -1,4 +1,7 @@
 import os
+# a CPU tool: 512 placeholder host devices stand in for the production mesh,
+# and it never takes a chip another process may hold
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512").strip()
 
@@ -468,12 +471,9 @@ def main(argv=None):
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args(argv)
 
-    # XLA_FLAGS merge is a no-op for flags already set (the module top pins
-    # the 512 host devices before jax import); schedules warm the plan cache
-    # so plan-lowered cells never autotune mid-sweep.
+    # schedules warm the plan cache so plan-lowered cells never autotune
+    # mid-sweep
     from repro.core.schedules import preload_schedules
-    from repro.launch.xla_flags import apply_xla_flags
-    apply_xla_flags()
     n_sched = preload_schedules()
     if n_sched:
         print(f"[dryrun] schedule zoo: {n_sched} GEMM schedules preloaded")

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.core.dispatch import policy_from_plan, use_policy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import decode_step, forward, init, init_cache, LOCAL
 from repro.models.transformer import prefill
 
@@ -44,13 +45,14 @@ def serve(cfg, params, prompts, gen_len: int, dist=LOCAL):
         batch["frames"] = jnp.zeros((B, cfg.enc_seq, cfg.d_model))
     last_logits, cache = prefill(params, cfg, batch, cache, dist)
 
-    step = jax.jit(lambda c, t: decode_step(params, cfg, c, t, dist))
+    # weights as an argument, so the executable carries no copy of them
+    step = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t, dist))
 
     out = []
     tok = jnp.argmax(last_logits, axis=-1)[:, None].astype(jnp.int32)
     for _ in range(gen_len):
         out.append(tok)
-        logits, cache = step(cache, tok)
+        logits, cache = step(params, cache, tok)
         tok = jnp.argmax(logits[:, 0], axis=-1)[:, None].astype(jnp.int32)
     return jnp.concatenate(out, axis=1)
 
@@ -99,8 +101,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.core.schedules import preload_schedules
-    from repro.launch.xla_flags import apply_xla_flags
-    apply_xla_flags()
+    enable_compile_cache()
     n_sched = preload_schedules(os.path.join(args.plans, "schedules"))
     if n_sched:
         print(f"[serve] schedule zoo: {n_sched} GEMM schedules preloaded "

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import auto_mesh
+
 PROFILES = ("fsdp", "ddp", "decode_tp")
 
 
@@ -40,7 +42,7 @@ def make_mesh(shape) -> jax.sharding.Mesh:
     n = jax.device_count()
     if r * c != n:
         raise ValueError(f"mesh {r}x{c} wants {r * c} devices, have {n}")
-    return jax.make_mesh((r, c), ("data", "model"))
+    return auto_mesh((r, c), ("data", "model"))
 
 
 def distribution_for(mesh, profile: str = "fsdp", numerics_policy=None):

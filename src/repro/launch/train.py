@@ -21,6 +21,7 @@ from repro.configs import get_config
 from repro.core.accumulator import AccumulatorSpec
 from repro.core.dispatch import policy_from_plan, use_policy
 from repro.data.synthetic import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.layers import Distribution, LOCAL
 from repro.core.qformat import parse_quant
 from repro.train.loop import Trainer, make_train_step
@@ -57,8 +58,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.core.schedules import preload_schedules
-    from repro.launch.xla_flags import apply_xla_flags
-    apply_xla_flags()
+    enable_compile_cache()
     n_sched = preload_schedules()
     if n_sched:
         print(f"[train] schedule zoo: {n_sched} GEMM schedules preloaded")

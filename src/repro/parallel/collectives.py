@@ -28,7 +28,6 @@ from repro.core import qformat
 from repro.core.accumulator import AccumulatorSpec
 from repro.core.qformat import QuantConfig
 from repro.obs.registry import default_registry as _obs_registry
-from repro.parallel.compat import axis_size
 
 _VALIDATE_OVERFLOW: Optional[str] = None     # None | "raise" | "warn"
 
@@ -138,7 +137,7 @@ def reproducible_psum(x: jax.Array, axis_name: str, spec: AccumulatorSpec,
     s = jax.lax.psum(q, axis_name)
     out = _grid_dequantize(s, spec.lsb, x.dtype)
     if mean:
-        out = out / axis_size(axis_name)
+        out = out / jax.lax.axis_size(axis_name)
     return out
 
 
@@ -197,7 +196,7 @@ def quantized_psum(x: jax.Array, axis_name: str, cfg: QuantConfig, *,
     if cfg.mode == "fp32":
         out = jax.lax.psum(x.astype(jnp.float32), axis_name)
         if mean:
-            out = out / axis_size(axis_name)
+            out = out / jax.lax.axis_size(axis_name)
         out = out.astype(x.dtype)
         if residual is None:
             return out
@@ -220,7 +219,7 @@ def quantized_psum(x: jax.Array, axis_name: str, cfg: QuantConfig, *,
 
     out = unblock(s.astype(jnp.float32) * scale[:, None])
     if mean:
-        out = out / axis_size(axis_name)
+        out = out / jax.lax.axis_size(axis_name)
     out = out.astype(x.dtype)
     if residual is None:
         return out
@@ -278,7 +277,7 @@ class CompressedGradReducer:
             red = jax.lax.psum(q, self.axis_name)
             return (_grid_dequantize(red, self.spec.lsb) / n).astype(g.dtype), new_r
 
-        n = axis_size(self.axis_name)
+        n = jax.lax.axis_size(self.axis_name)
         flat_g, td = jax.tree.flatten(grads)
         flat_r = jax.tree.leaves(residual)
         out = [one(g, r) for g, r in zip(flat_g, flat_r)]

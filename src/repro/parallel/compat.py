@@ -1,35 +1,13 @@
-"""Version shims for JAX APIs whose signatures changed across releases."""
+"""``shard_map`` with replication checking off, the one form this code uses."""
 
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.6 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking disabled.
-
-    The flag was renamed ``check_rep`` -> ``check_vma`` across JAX releases;
-    try the new name first so both old (0.4.x) and new JAX work."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
-
-def axis_size(axis_name) -> jax.Array:
-    """Size of a bound mesh axis (or tuple of axes), as a traced scalar.
-
-    Newer JAX exposes ``jax.lax.axis_size``; on older releases ``psum`` of a
-    constant 1 constant-folds to the same static count inside shard_map (one
-    scalar per call site — not a per-leaf ones-tensor reduction)."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:  # pragma: no cover - depends on jax version
-        return jax.lax.psum(1, axis_name)
+    """``jax.shard_map`` with ``check_vma=False``: the FDP collectives and
+    the MoE dispatch return per-device values that the varying-axes check
+    cannot see are replicated."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -92,6 +92,8 @@ def main(argv=None):
     import os
 
     from repro.core.schedules import preload_schedules
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     n_sched = preload_schedules(os.path.join(args.plans, "schedules"))
 
     cfg = get_config(args.arch)

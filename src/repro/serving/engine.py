@@ -123,9 +123,10 @@ class ScoreEngine:
                  policy: Optional[NumericsPolicy]):
         self.bucket = bucket
         self.method = "score"
+        self.params = params
         self.trace_count = 0
 
-        def fn(tokens, mask):
+        def fn(params, tokens, mask):
             self.trace_count += 1            # python side effect: trace only
             batch = {"tokens": tokens}
             if cfg.family == "vlm":
@@ -146,7 +147,7 @@ class ScoreEngine:
         mask0 = jnp.zeros((bucket.n_slots, bucket.max_len), jnp.float32)
         ctx = use_policy(policy) if policy is not None else _nullctx()
         with ctx:
-            self._fn = jax.jit(fn).lower(tok0, mask0).compile()
+            self._fn = jax.jit(fn).lower(params, tok0, mask0).compile()
 
     def idle(self) -> bool:
         return True                          # one-shot: no resident state
@@ -162,7 +163,8 @@ class ScoreEngine:
         for i, p in enumerate(prompts):
             toks[i, :len(p)] = p
             mask[i, :len(p)] = 1.0
-        out = np.asarray(self._fn(jnp.asarray(toks), jnp.asarray(mask)))
+        out = np.asarray(self._fn(self.params, jnp.asarray(toks),
+                                  jnp.asarray(mask)))
         return [float(out[i]) for i in range(len(prompts))]
 
 

@@ -204,13 +204,12 @@ def sharded_value_and_grad(loss_fn, axis_names, *,
     stateful deployment concern — carry it with
     ``parallel.collectives.QuantizedGradReducer``, not here.
     """
-    from repro.parallel.compat import axis_size
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def fn(params, batch):
         (loss, aux), grads = grad_fn(params, batch)
-        n = axis_size(axis_names)
+        n = jax.lax.axis_size(axis_names)
         if fdp_grad_spec is not None:
             scale = 2.0 ** fdp_grad_spec.lsb
 

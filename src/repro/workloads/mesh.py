@@ -87,6 +87,7 @@ class MeshReshapeStability(Validator):
         from jax.sharding import PartitionSpec as P
 
         from repro.core.dispatch import gemm
+        from repro.launch.mesh import auto_mesh
         from repro.parallel.compat import shard_map_unchecked
 
         a, b = jnp.asarray(self.a), jnp.asarray(self.b)
@@ -94,7 +95,7 @@ class MeshReshapeStability(Validator):
         axes = ("data", "model")
         outs = []
         for r, c in self.shapes:
-            mesh = jax.make_mesh((r, c), axes)
+            mesh = auto_mesh((r, c), axes)
 
             def f(al, bl):
                 return gemm(al, bl, site=site, policy=policy,
@@ -115,6 +116,7 @@ class MeshReshapeStability(Validator):
         from repro.core.dispatch import use_policy
         from repro.models import forward
         from repro.models.layers import LOCAL
+        from repro.launch.mesh import auto_mesh
         from repro.parallel.compat import shard_map_unchecked
         from repro.train.loop import make_loss_fn, sharded_value_and_grad
 
@@ -135,7 +137,7 @@ class MeshReshapeStability(Validator):
 
         logits_all, grads_all = [], []
         for r, c in self.shapes:
-            mesh = jax.make_mesh((r, c), axes)
+            mesh = auto_mesh((r, c), axes)
             sharded = shard_map_unchecked(
                 body, mesh=mesh, in_specs=(P(), P(axes)),
                 out_specs=(P(axes), P()))

@@ -1,8 +1,8 @@
 import numpy as np
 import pytest
-from fractions import Fraction
 
-import jax
+# the host Fraction oracle, shared with chip_smoke.py
+from repro.core.metrics import fdp_oracle, frac_to_f32_rne  # noqa: F401
 
 
 @pytest.fixture(scope="session")
@@ -19,36 +19,3 @@ def clean_sites():
     dispatch.reset_sites_seen()
     yield dispatch.sites_seen
     dispatch.reset_sites_seen()
-
-
-def frac_to_f32_rne(f: Fraction) -> np.float32:
-    """Correct single RNE from Fraction to float32 (test oracle helper)."""
-    if f == 0:
-        return np.float32(0.0)
-    s = -1 if f < 0 else 1
-    f = abs(f)
-    e = f.numerator.bit_length() - f.denominator.bit_length() - 23
-    while f / Fraction(2) ** e >= 2 ** 24:
-        e += 1
-    while f / Fraction(2) ** e < 2 ** 23:
-        e -= 1
-    m = f / Fraction(2) ** e
-    mi = int(m)
-    rem = m - mi
-    if rem > Fraction(1, 2) or (rem == Fraction(1, 2) and mi % 2 == 1):
-        mi += 1
-    return np.float32(s * np.ldexp(np.float64(mi), e))
-
-
-def fdp_oracle(a, b, spec) -> np.float32:
-    """Host-side normative semantics: per-product trunc at 2^lsb, exact sum,
-    W-bit wrap, single RNE to f32."""
-    exact = Fraction(0)
-    scale = Fraction(2) ** spec.lsb
-    for x, y in zip(np.asarray(a, np.float64).tolist(),
-                    np.asarray(b, np.float64).tolist()):
-        p = Fraction(x) * Fraction(y)
-        exact += int(abs(p) / scale) * (1 if p >= 0 else -1)
-    W = spec.width
-    wrapped = ((int(exact) + 2 ** (W - 1)) % 2 ** W) - 2 ** (W - 1)
-    return frac_to_f32_rne(Fraction(wrapped) * scale)

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import auto_mesh
 from repro.parallel.compat import shard_map_unchecked
 
 from repro.core.accumulator import AccumulatorSpec
@@ -26,7 +27,7 @@ from repro.parallel.pipeline import pipeline_apply
 def check_reproducible_psum():
     """Integer psum is bitwise order-invariant; check quantize/psum/dequant
     matches a float reference within grid resolution and is deterministic."""
-    mesh = jax.make_mesh((8,), ("dp",))
+    mesh = auto_mesh((8,), ("dp",))
     spec = AccumulatorSpec(ovf=8, msb=8, lsb=-16)
     x = jax.random.normal(jax.random.key(0), (8, 64))
 
@@ -52,7 +53,7 @@ def _moe_cfg(E=4, k=2):
 
 def check_moe_tp_parity():
     """shard_map TP-MoE == local MoE (fp32)."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     dist = Distribution(mesh=mesh, dp_axes=("data",), tp_axis="model")
     cfg = _moe_cfg()
     p = MOE.init_moe(jax.random.key(0), cfg.d_model, cfg.d_ff, cfg.n_experts)
@@ -67,7 +68,7 @@ def check_moe_tp_parity():
 
 def check_moe_ep_parity():
     """EP all-to-all MoE == local MoE when capacity is ample (fp32)."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     dist = Distribution(mesh=mesh, dp_axes=("data",), tp_axis="model")
     cfg = _moe_cfg(E=8, k=2)
     p = MOE.init_moe(jax.random.key(0), cfg.d_model, cfg.d_ff, cfg.n_experts)
@@ -83,7 +84,7 @@ def check_moe_ep_parity():
 
 def check_pipeline_parity():
     """4-stage GPipe == sequential layer stack."""
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = auto_mesh((4,), ("stage",))
     S, n_micro, mb, d = 4, 8, 2, 16
     keys = jax.random.split(jax.random.key(0), S)
     params = {"w": jnp.stack([jax.random.normal(k, (d, d)) / d ** 0.5
@@ -106,7 +107,7 @@ def check_sp_forward_parity():
     """Sequence-parallel sharded forward == single-device forward (fp32)."""
     from repro.configs import get_config
     from repro.models import forward, init
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     dist = Distribution(mesh=mesh, dp_axes=("data",), tp_axis="model")
     cfg = get_config("llama3.2-3b").reduced()
     params = init(cfg, jax.random.key(0))
@@ -128,7 +129,7 @@ def check_fdp_limb_psum():
     from repro.parallel.collectives import fdp_psum
 
     spec = AccumulatorSpec(ovf=30, msb=30, lsb=-30)
-    mesh = jax.make_mesh((8,), ("x",))
+    mesh = auto_mesh((8,), ("x",))
     a = jax.random.normal(jax.random.key(0), (8, 256))
     b = jax.random.normal(jax.random.key(1), (256, 16))
     ref = np.asarray(fdp.fdp_gemm(a, b, spec))
@@ -187,7 +188,7 @@ def check_mesh_reshape_logits():
     grad_spec = AccumulatorSpec(ovf=10, msb=10, lsb=-20)
     stepped = []
     for shape in ((1, 8), (2, 4), (8, 1)):
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = auto_mesh(shape, ("data", "model"))
         dist = distribution_for(mesh, "ddp", numerics_policy=policy)
         step = make_mesh_train_step(cfg, opt, dist, fdp_grad_spec=grad_spec)
         (params, _), _metrics = step((ctx.params, opt.init(ctx.params)),
@@ -209,7 +210,7 @@ def check_quantized_psum():
     from repro.core.qformat import QuantConfig
     from repro.parallel.collectives import quantized_psum, validate_overflow
 
-    mesh = jax.make_mesh((8,), ("dp",))
+    mesh = auto_mesh((8,), ("dp",))
     cfg = QuantConfig(4, 32)
     g = jax.random.normal(jax.random.key(0), (8, 64)) * 0.1
 
@@ -247,7 +248,7 @@ def check_quantized_psum():
 
 def check_compressed_grads():
     from repro.parallel.collectives import CompressedGradReducer
-    mesh = jax.make_mesh((8,), ("dp",))
+    mesh = auto_mesh((8,), ("dp",))
     spec = AccumulatorSpec(ovf=4, msb=2, lsb=-8)   # coarse grid (compression)
     red = CompressedGradReducer(spec, "dp")
     g = jax.random.normal(jax.random.key(0), (8, 32)) * 0.1

@@ -13,6 +13,7 @@ import pytest
 from repro.checkpoint.store import CheckpointStore
 from repro.configs import get_config
 from repro.data.synthetic import SyntheticLM
+from repro.launch.mesh import auto_mesh
 from repro.models import LOCAL, init
 from repro.train.loop import InjectedFailure, Trainer, make_train_step
 from repro.train.optimizer import adamw
@@ -104,7 +105,7 @@ def test_elastic_restore_resharding(tmp_path):
     store = CheckpointStore(str(tmp_path))
     t = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     store.save(1, t)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     sh = {"w": NamedSharding(mesh, P("data", "model"))}
     step, got = store.load_latest(shardings=sh)
     assert got["w"].sharding == sh["w"]

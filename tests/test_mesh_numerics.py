@@ -18,13 +18,14 @@ from repro.core.accumulator import AccumulatorSpec
 from repro.core.dispatch import FDP91, MXU_FP32, gemm, use_policy
 from repro.parallel.collectives import (fdp_psum, reproducible_psum,
                                         validate_overflow, _grid_quantize)
-from repro.parallel.compat import axis_size, shard_map_unchecked
+from repro.launch.mesh import auto_mesh
+from repro.parallel.compat import shard_map_unchecked
 
 SPEC = AccumulatorSpec(ovf=30, msb=30, lsb=-30)
 
 
 def _mesh1():
-    return jax.make_mesh((1,), ("x",))
+    return auto_mesh((1,), ("x",))
 
 
 # ---------------------------------------------------------------------------
@@ -130,7 +131,7 @@ def test_gemm_reduce_axis_fdp_rejects_batched():
 
 
 # ---------------------------------------------------------------------------
-# Collective payload overflow guard + axis_size shim
+# Collective payload overflow guard + axis_size
 # ---------------------------------------------------------------------------
 def test_overflow_guard_raises_under_validation():
     with validate_overflow():
@@ -150,7 +151,7 @@ def test_overflow_guard_clean_path_and_default_off():
 def test_axis_size_and_mean_psum():
     def f(xl):
         return reproducible_psum(xl[0], "x", AccumulatorSpec(8, 8, -16),
-                                 mean=True), axis_size("x")
+                                 mean=True), jax.lax.axis_size("x")
 
     x = jax.random.normal(jax.random.key(10), (1, 16))
     out, n = shard_map_unchecked(f, mesh=_mesh1(), in_specs=P("x"),

@@ -4,14 +4,13 @@ every profile — the static half of what the dry-run proves by compiling."""
 
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config, all_arch_names
-from repro.launch.mesh import abstract_mesh
 from repro.launch.sharding import param_specs
 from repro.models import transformer as T
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 AXIS = dict(MESH.shape)
 AXIS_MP = {"pod": 2, **AXIS}
 
