@@ -99,6 +99,16 @@ class GemmSite:
         return (f"{self.name}@{self.phase}.{self.operand}"
                 if self.operand else f"{self.name}@{self.phase}")
 
+    @property
+    def scope(self) -> str:
+        """The ``jax.named_scope`` its contraction runs under, spelled to
+        survive into the compiled HLO's ``op_name`` metadata (a scope's
+        ``@...`` is cut there): ``site.attn_qk.fwd``,
+        ``site.attn_qk.bwd.dA``. Profiler op time maps back to the site
+        through it."""
+        return ".".join(filter(None, ("site", self.name, self.phase,
+                                      self.operand)))
+
     def bwd(self, operand: str) -> "GemmSite":
         return GemmSite(self.name, "bwd", operand)
 
@@ -621,9 +631,11 @@ def _dispatch(site: GemmSite, cfg: GemmConfig, a: Array, b: Array, *,
     """Run one matmul as one *site*: register the key, execute under the
     resolved config, report to the calibration hook. Every entry point —
     forward and backward — funnels through here so phase-qualified sites are
-    first-class everywhere (``sites_seen``, traces, plans)."""
+    first-class everywhere (``sites_seen``, traces, plans). The
+    contraction runs under the site's ``named_scope`` (``GemmSite.scope``)."""
     _note_site(site.key)
-    out = _execute(cfg, a, b, plan=plan)
+    with jax.named_scope(site.scope):
+        out = _execute(cfg, a, b, plan=plan)
     return _maybe_trace(site.key, cfg, a, b, out)
 
 
@@ -706,7 +718,8 @@ def _acc_to_float(spec: AccumulatorSpec, limbs: Array) -> Array:
 def _dispatch_reduce(site: GemmSite, cfg: GemmConfig, a: Array, b: Array,
                      axis_name) -> Array:
     _note_site(site.key)
-    out = _execute_reduce(cfg, a, b, axis_name)
+    with jax.named_scope(site.scope):
+        out = _execute_reduce(cfg, a, b, axis_name)
     return _maybe_trace(site.key, cfg, a, b, out)
 
 
@@ -804,8 +817,9 @@ def _grouped_qk_execute(site: GemmSite, cfg: GemmConfig,
     _note_site(site.key)
     if cfg.mode == "native":
         dt = cfg.fmt.jnp_dtype
-        out = jnp.einsum("bkgqd,bksd->bkgqs", q.astype(dt), k.astype(dt),
-                         preferred_element_type=jnp.float32)
+        with jax.named_scope(site.scope):
+            out = jnp.einsum("bkgqd,bksd->bkgqs", q.astype(dt), k.astype(dt),
+                             preferred_element_type=jnp.float32)
         if _TRACE_HOOK is not None:
             # report in jnp.matmul shape so the profiler sees the real
             # contraction: (B,Kh,G*Sq,hd) x (B,Kh,hd,Sk)
@@ -826,8 +840,9 @@ def _grouped_av_execute(site: GemmSite, cfg: GemmConfig,
     _note_site(site.key)
     if cfg.mode == "native":
         dt = cfg.fmt.jnp_dtype
-        out = jnp.einsum("bkgqs,bksd->bkgqd", p.astype(dt), v.astype(dt),
-                         preferred_element_type=jnp.float32)
+        with jax.named_scope(site.scope):
+            out = jnp.einsum("bkgqs,bksd->bkgqd", p.astype(dt), v.astype(dt),
+                             preferred_element_type=jnp.float32)
         if _TRACE_HOOK is not None:
             B_, Kh_, G_, Sq_, Sk_ = p.shape
             _maybe_trace(site.key, cfg, p.reshape(B_, Kh_, G_ * Sq_, Sk_), v,
@@ -847,8 +862,10 @@ def _grouped_dright(site: GemmSite, cfg: GemmConfig,
     _note_site(site.key)
     if cfg.mode == "native":
         dt = cfg.fmt.jnp_dtype
-        out = jnp.einsum("bkgqx,bkgqy->bkxy", lhs.astype(dt), rhs.astype(dt),
-                         preferred_element_type=jnp.float32)
+        with jax.named_scope(site.scope):
+            out = jnp.einsum("bkgqx,bkgqy->bkxy", lhs.astype(dt),
+                             rhs.astype(dt),
+                             preferred_element_type=jnp.float32)
         if _TRACE_HOOK is not None:
             B_, Kh_, G_, Sq_, X_ = lhs.shape
             _maybe_trace(site.key, cfg,
@@ -958,32 +975,33 @@ def _ragged_execute(site: GemmSite, cfg: GemmConfig, x: Array, w: Array,
     which is the same ragged contraction against transposed weights)."""
     _note_site(site.key)
     E, d, f = w.shape
-    if cfg.mode == "native":
-        dt = cfg.fmt.jnp_dtype
-        out = jax.lax.ragged_dot(x.astype(dt), w.astype(dt), group_sizes,
-                                 preferred_element_type=jnp.float32)
-    elif cfg.mode == "pallas":
-        # Sorted-segment kernel: rows are already sorted by group, so the
-        # Pallas grid walks contiguous segments with a per-tile expert-weight
-        # index map — O(T·d·f) MACs instead of the reference path's T×E.
-        # Exact integer limb accumulation is order-invariant, so the result
-        # is bit-identical to the reference grouped path below.
-        from repro.kernels import ops as kops
-        if isinstance(cfg.fmt, FloatFormat):
-            x, w = cfg.fmt.quantize(x), cfg.fmt.quantize(w)
-        plan = plan_gemm(x.shape[0], f, d, fmt=cfg.fmt, spec=cfg.acc)
-        plan = _fit_ragged(plan, "bm", x.shape[0], E)
-        out = kops.fdp_ragged_gemm(x, w, group_sizes, spec=cfg.acc,
-                                   fmt=cfg.fmt, plan=plan)
-    else:
-        seg = _segment_ids(group_sizes, x.shape[0])              # (T,)
-        per_expert = jax.vmap(lambda we: _execute(cfg, x, we))(w)  # (E,T,f)
-        out = jnp.take_along_axis(
-            per_expert, jnp.minimum(seg, E - 1)[None, :, None], axis=0)[0]
-        # rows beyond sum(group_sizes) (padding) belong to no group: zero
-        # them like the native ragged_dot path, so flipping a site between
-        # native and FDP candidates never changes padded-row outputs
-        out = jnp.where((seg < E)[:, None], out, 0.0)
+    with jax.named_scope(site.scope):
+        if cfg.mode == "native":
+            dt = cfg.fmt.jnp_dtype
+            out = jax.lax.ragged_dot(x.astype(dt), w.astype(dt), group_sizes,
+                                     preferred_element_type=jnp.float32)
+        elif cfg.mode == "pallas":
+            # Sorted-segment kernel: rows are already sorted by group, so the
+            # Pallas grid walks contiguous segments with a per-tile expert-weight
+            # index map — O(T·d·f) MACs instead of the reference path's T×E.
+            # Exact integer limb accumulation is order-invariant, so the result
+            # is bit-identical to the reference grouped path below.
+            from repro.kernels import ops as kops
+            if isinstance(cfg.fmt, FloatFormat):
+                x, w = cfg.fmt.quantize(x), cfg.fmt.quantize(w)
+            plan = plan_gemm(x.shape[0], f, d, fmt=cfg.fmt, spec=cfg.acc)
+            plan = _fit_ragged(plan, "bm", x.shape[0], E)
+            out = kops.fdp_ragged_gemm(x, w, group_sizes, spec=cfg.acc,
+                                       fmt=cfg.fmt, plan=plan)
+        else:
+            seg = _segment_ids(group_sizes, x.shape[0])              # (T,)
+            per_expert = jax.vmap(lambda we: _execute(cfg, x, we))(w)  # (E,T,f)
+            out = jnp.take_along_axis(
+                per_expert, jnp.minimum(seg, E - 1)[None, :, None], axis=0)[0]
+            # rows beyond sum(group_sizes) (padding) belong to no group: zero
+            # them like the native ragged_dot path, so flipping a site between
+            # native and FDP candidates never changes padded-row outputs
+            out = jnp.where((seg < E)[:, None], out, 0.0)
     # report as one (T, d) x (d, f) call: k/m from x, n and weight stats from
     # the flattened expert stack (the sample decoder reshapes (-1, d, f) and
     # keeps group 0's block)
@@ -1018,24 +1036,25 @@ def _ragged_vjp_bwd(ctx, res, g):
     # native configs are not asymptotically worse than autodiff here.
     dw_cfg = pol.lookup(dw_site)
     _note_site(dw_site.key)
-    if dw_cfg.mode == "pallas":
-        from repro.kernels import ops as kops
-        xq, gq = x, g
-        if isinstance(dw_cfg.fmt, FloatFormat):
-            xq, gq = dw_cfg.fmt.quantize(x), dw_cfg.fmt.quantize(g)
-        plan = plan_gemm(d, f, x.shape[0], fmt=dw_cfg.fmt, spec=dw_cfg.acc)
-        plan = _fit_ragged(plan, "bk", x.shape[0], E)
-        dw = kops.fdp_ragged_dw(xq, gq, group_sizes, num_groups=E,
-                                spec=dw_cfg.acc, fmt=dw_cfg.fmt, plan=plan)
-    else:
-        seg = _segment_ids(group_sizes, x.shape[0])
-        masks = seg[None, :] == jnp.arange(E)[:, None]           # (E, T)
+    with jax.named_scope(dw_site.scope):
+        if dw_cfg.mode == "pallas":
+            from repro.kernels import ops as kops
+            xq, gq = x, g
+            if isinstance(dw_cfg.fmt, FloatFormat):
+                xq, gq = dw_cfg.fmt.quantize(x), dw_cfg.fmt.quantize(g)
+            plan = plan_gemm(d, f, x.shape[0], fmt=dw_cfg.fmt, spec=dw_cfg.acc)
+            plan = _fit_ragged(plan, "bk", x.shape[0], E)
+            dw = kops.fdp_ragged_dw(xq, gq, group_sizes, num_groups=E,
+                                    spec=dw_cfg.acc, fmt=dw_cfg.fmt, plan=plan)
+        else:
+            seg = _segment_ids(group_sizes, x.shape[0])
+            masks = seg[None, :] == jnp.arange(E)[:, None]           # (E, T)
 
-        def per_expert(m):
-            xm = jnp.where(m[:, None], x, jnp.zeros((), x.dtype))
-            return _execute(dw_cfg, jnp.swapaxes(xm, -1, -2), g)   # (d, f)
+            def per_expert(m):
+                xm = jnp.where(m[:, None], x, jnp.zeros((), x.dtype))
+                return _execute(dw_cfg, jnp.swapaxes(xm, -1, -2), g)   # (d, f)
 
-        dw = jax.vmap(per_expert)(masks)                         # (E, d, f)
+            dw = jax.vmap(per_expert)(masks)                         # (E, d, f)
     _maybe_trace(dw_site.key, dw_cfg, jnp.swapaxes(x, -1, -2), g,
                  dw.reshape(E * d, f))
     zeros_gs = np.zeros(group_sizes.shape, dtype=jax.dtypes.float0)

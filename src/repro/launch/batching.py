@@ -25,6 +25,16 @@ import numpy as np
 from repro.core.dispatch import NumericsPolicy, policy_from_plan, use_policy
 from repro.models import decode_step, init_cache
 from repro.models.layers import LOCAL
+from repro.obs.registry import default_registry
+from repro.obs.spans import phase, span
+
+# A slot-step is one live slot in one engine step, named by what it fed:
+#   prefill       a prompt token, sampling nothing
+#   prefill_last  the prompt's last token, sampling the first output
+#   decode        the previous output, sampling the next one
+# so, summed over requests, Request.prefill_tokens = prefill + prefill_last
+# and Request.decode_tokens = prefill_last + decode.
+SLOT_KINDS = ("prefill", "prefill_last", "decode")
 
 
 def _resolve_policy(policy) -> Optional[NumericsPolicy]:
@@ -115,6 +125,17 @@ class ContinuousBatcher:
         # traced exactly once per engine when warmed up — the regression
         # guard for "warmup must compile under the serving policy"
         self.trace_count = 0
+        # what this engine did, counted where it happens (plain ints, with
+        # process-wide registry mirrors): engine steps run, and slot-steps
+        # by SLOT_KINDS
+        self.steps_run = 0
+        self.slot_steps = dict.fromkeys(SLOT_KINDS, 0)
+        reg = default_registry()
+        self._m_steps = reg.counter(
+            "repro_batcher_steps_total", "engine steps run by batchers")
+        self._m_slot_steps = reg.counter(
+            "repro_batcher_slot_steps_total",
+            "live slots fed by batchers' engine steps", ("kind",))
 
         # the weights are an argument, not a closure: a closed-over array
         # becomes a constant of the executable, and every (plan, bucket)
@@ -159,11 +180,12 @@ class ContinuousBatcher:
         if any(r is not None for r in self.active):
             raise RuntimeError("reset_cache with live slots would destroy "
                                "in-flight generations; drain first")
-        self.cache = init_cache(self.cfg, self.n_slots, self.max_len,
-                                dtype=jnp.float32)
-        self._len = 0
-        self._start[:] = 0
-        self.cache["start"] = jnp.zeros((self.n_slots,), jnp.int32)
+        with phase("batcher.reset_cache"):
+            self.cache = init_cache(self.cfg, self.n_slots, self.max_len,
+                                    dtype=jnp.float32)
+            self._len = 0
+            self._start[:] = 0
+            self.cache["start"] = jnp.zeros((self.n_slots,), jnp.int32)
 
     def stats(self):
         """Typed ``PlanCacheStats`` for the process-global GemmPlan cache —
@@ -175,6 +197,15 @@ class ContinuousBatcher:
            scrape the registry for monitoring."""
         from repro.core import dispatch
         return dispatch.plan_cache_stats()
+
+    def step_hlo_text(self) -> Optional[str]:
+        """The compiled decode step's optimized HLO text (``None`` for an
+        engine that was not warmed up, whose step compiles lazily). A
+        profiler's op events name the step's instructions; this text maps
+        each to its ``op_name``, whose ``site.*`` scope names the GEMM site
+        it ran for."""
+        as_text = getattr(self._step, "as_text", None)
+        return as_text() if as_text is not None else None
 
     def numerics_info(self) -> dict:
         """GemmPlan cache + call-site report for this engine's decode step
@@ -219,27 +250,46 @@ class ContinuousBatcher:
         return jnp.asarray(toks)
 
     def step(self):
-        """One engine step: feed one token per active slot."""
-        self._fill_slots()
-        if all(r is None for r in self.active):
-            return False
-        toks = self._next_tokens()
-        # non-warmed engines trace lazily on the first step; entering the
-        # policy context here keeps that trace (and any retrace) under the
-        # same numerics the warmup path compiles with
-        with self._policy_ctx():
-            logits, self.cache = self._step(self.params, self.cache, toks)
-        self._len += 1
-        nxt = np.asarray(jnp.argmax(logits[:, 0, :self.cfg.vocab_size], -1))
+        """One engine step: feed one token per active slot. Its parts are
+        profiler phases (``batcher.fill`` ... ``batcher.deliver``)."""
+        with phase("batcher.step"):
+            with phase("batcher.fill"):
+                self._fill_slots()
+            if all(r is None for r in self.active):
+                return False
+            with phase("batcher.prepare"):
+                toks = self._next_tokens()
+            # non-warmed engines trace lazily on the first step; entering the
+            # policy context here keeps that trace (and any retrace) under the
+            # same numerics the warmup path compiles with
+            with phase("batcher.launch"), self._policy_ctx():
+                logits, self.cache = self._step(self.params, self.cache, toks)
+            self._len += 1
+            with phase("batcher.sample"):
+                nxt = np.asarray(
+                    jnp.argmax(logits[:, 0, :self.cfg.vocab_size], -1))
+            with phase("batcher.deliver"):
+                self._deliver(nxt)
+        return True
+
+    def _deliver(self, nxt) -> None:
+        """After a step: advance every live slot, hand out its sampled
+        token, free finished slots, and count the step's slot-steps."""
+        fed = dict.fromkeys(SLOT_KINDS, 0)
         for i, req in enumerate(self.active):
             if req is None:
                 continue
             self._fed[i] += 1
             req.steps += 1
-            if self._fed[i] <= len(req.prompt):
-                req.prefill_tokens += 1          # this step fed a prompt token
             if self._fed[i] < len(req.prompt):
+                req.prefill_tokens += 1
+                fed["prefill"] += 1
                 continue                                # still prefilling
+            if self._fed[i] == len(req.prompt):
+                req.prefill_tokens += 1
+                fed["prefill_last"] += 1
+            else:
+                fed["decode"] += 1
             req.out.append(int(nxt[i]))
             req.decode_tokens += 1
             if req.on_token is not None:
@@ -252,7 +302,12 @@ class ContinuousBatcher:
             if len(req.out) >= req.max_new or hit_eos or at_wall:
                 req.done = True
                 self.active[i] = None                   # slot freed
-        return True
+        self.steps_run += 1
+        self._m_steps.inc()
+        for kind, n in fed.items():
+            if n:
+                self.slot_steps[kind] += n
+                self._m_slot_steps.inc(n, kind=kind)
 
     def run(self, max_steps: int = 10_000) -> None:
         """Drive until the queue and all slots drain (or max_steps).
@@ -260,7 +315,6 @@ class ContinuousBatcher:
         Raises ``CacheExhausted`` when the queue is non-empty but nothing can
         ever be admitted (the global cursor has outrun the cache) — loud
         refusal instead of the old silent truncation."""
-        from repro.obs.spans import span
         with span("serving.batcher_run", n_slots=self.n_slots,
                   max_len=self.max_len) as sp:
             steps = 0

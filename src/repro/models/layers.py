@@ -296,31 +296,34 @@ def attention_block(x: Array, p, cfg, dist: Distribution, *,
 
     new_cache = None
     if kv_cache is not None:
-        # incremental decode: write k,v at position len, attend to prefix
+        # incremental decode: write k,v at position len, attend to prefix.
+        # The writes run under a ``kv_cache`` scope, so a profile names them.
         ln = kv_cache["len"]
         if "k_scale" in kv_cache:      # int8 tailored cache
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            kfull = jax.lax.dynamic_update_slice_in_dim(
-                kv_cache["k"], kq, ln, axis=2)
-            vfull = jax.lax.dynamic_update_slice_in_dim(
-                kv_cache["v"], vq, ln, axis=2)
-            ksf = jax.lax.dynamic_update_slice_in_dim(
-                kv_cache["k_scale"], ks.astype(kv_cache["k_scale"].dtype),
-                ln, axis=2)
-            vsf = jax.lax.dynamic_update_slice_in_dim(
-                kv_cache["v_scale"], vs.astype(kv_cache["v_scale"].dtype),
-                ln, axis=2)
+            with jax.named_scope("kv_cache"):
+                kq, ks = quantize_kv(k)
+                vq, vs = quantize_kv(v)
+                kfull = jax.lax.dynamic_update_slice_in_dim(
+                    kv_cache["k"], kq, ln, axis=2)
+                vfull = jax.lax.dynamic_update_slice_in_dim(
+                    kv_cache["v"], vq, ln, axis=2)
+                ksf = jax.lax.dynamic_update_slice_in_dim(
+                    kv_cache["k_scale"], ks.astype(kv_cache["k_scale"].dtype),
+                    ln, axis=2)
+                vsf = jax.lax.dynamic_update_slice_in_dim(
+                    kv_cache["v_scale"], vs.astype(kv_cache["v_scale"].dtype),
+                    ln, axis=2)
             out = decode_attention(q, kfull, vfull, cache_len=ln + S,
                                    k_scale=ksf, v_scale=vsf,
                                    start=kv_cache.get("start"), site=site)
             new_cache = {"k": kfull, "v": vfull, "k_scale": ksf,
                          "v_scale": vsf, "len": ln + S}
         else:
-            kfull = jax.lax.dynamic_update_slice_in_dim(kv_cache["k"], k, ln,
-                                                        axis=2)
-            vfull = jax.lax.dynamic_update_slice_in_dim(kv_cache["v"], v, ln,
-                                                        axis=2)
+            with jax.named_scope("kv_cache"):
+                kfull = jax.lax.dynamic_update_slice_in_dim(
+                    kv_cache["k"], k, ln, axis=2)
+                vfull = jax.lax.dynamic_update_slice_in_dim(
+                    kv_cache["v"], v, ln, axis=2)
             out = decode_attention(q, kfull, vfull, cache_len=ln + S,
                                    start=kv_cache.get("start"), site=site)
             new_cache = {"k": kfull, "v": vfull, "len": ln + S}

@@ -8,17 +8,19 @@
 #              dispatch trace-hook seam: inside / near-edge / violated, with
 #              overflow counting and pluggable alert sinks
 #   spans    - lightweight trace spans (serving request lifecycle, train
-#              steps, AOT compiles) exporting Chrome-trace/Perfetto JSON,
-#              with per-plan energy attribution
+#              steps, AOT compiles) exporting Chrome-trace/Perfetto JSON;
+#              scoped spans and hot-path phases also land in a running
+#              jax.profiler trace
 #
-# ``registry``/``spans`` import eagerly (stdlib-only, safe from the lowest
-# layers — core.dispatch mirrors its plan-cache stats here). ``monitor`` and
+# ``registry``/``spans`` import eagerly (stdlib-only at import, safe from
+# the lowest layers — core.dispatch mirrors its plan-cache stats here; a
+# phase resolves jax.profiler on first use). ``monitor`` and
 # ``export`` resolve lazily: monitor pulls in jax + dispatch, and eager
 # loading would cycle through core.dispatch's own import of this package.
 from .registry import (Counter, Gauge, Histogram, MetricError, Registry,
                        default_registry)
-from .spans import (Span, SpanRecorder, current_span, plan_energy_per_token,
-                    recorder, span, start_span)
+from .spans import (Span, SpanRecorder, current_span, phase, recorder, span,
+                    start_span)
 
 _LAZY = {
     "monitor": ".monitor", "export": ".export",
@@ -33,8 +35,8 @@ _LAZY = {
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricError", "Registry",
     "default_registry",
-    "Span", "SpanRecorder", "current_span", "plan_energy_per_token",
-    "recorder", "span", "start_span",
+    "Span", "SpanRecorder", "current_span", "phase", "recorder", "span",
+    "start_span",
     *sorted(set(_LAZY) - {"monitor", "export"}),
 ]
 

@@ -8,16 +8,20 @@ in a bounded in-process recorder and export as Chrome-trace/Perfetto JSON via
 :mod:`repro.obs.export` (``--trace-out trace.json`` on the launch drivers;
 open in ``chrome://tracing`` or https://ui.perfetto.dev).
 
-Cost model: recording is a perf_counter pair, a dict, and a deque append —
-cheap enough to leave on per decode step. The recorder is a ring buffer
-(default 20k events) so long-running servers never grow without bound; the
-drop count is reported so truncation is visible, not silent.
+While a ``jax.profiler`` trace is running, a scoped ``span`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so it lands on the
+profiler's host plane, on the clock the device events are placed on.
+``start_span`` lifecycles stay recorder-only: a profiler annotation must
+close on the thread that opened it, innermost first. Hot-path phases (one
+per part of every engine step and frontend tick) use :func:`phase`, which
+goes to the profiler only and records nothing here, so a long serving run
+never pushes its request spans out of the ring buffer.
 
-Energy attribution: :func:`plan_energy_per_token` folds a deployed
-``PrecisionPlan``'s per-site MAC counts through ``core.energy.gemm_power``
-into joules per token, so harvest-time spans (and the
-``repro_serving_energy_joules_total`` counter) carry a live energy meter per request
-class — the paper's modeled-energy axis, running against production traffic.
+Cost model: recording is a perf_counter pair, a dict, and a deque append —
+cheap enough to leave on per decode step. With no profiler session running,
+``phase`` is one check and a shared no-op context. The recorder is a ring
+buffer (default 20k events) so long-running servers never grow without
+bound; the drop count is reported so truncation is visible, not silent.
 """
 
 from __future__ import annotations
@@ -79,7 +83,7 @@ def _stack() -> list:
 
 class Span:
     """One in-flight interval. ``end()`` is idempotent; extra keyword args
-    to ``end`` merge into the recorded attributes (steps, tokens, energy)."""
+    to ``end`` merge into the recorded attributes (steps, tokens, queue time)."""
 
     __slots__ = ("name", "args", "_t0", "_ts_us", "_tid", "_ended",
                  "_recorder", "_on_stack")
@@ -136,11 +140,13 @@ def start_span(name: str, **args) -> Span:
 @contextlib.contextmanager
 def span(name: str, **args):
     """Scoped span; nests via a thread-local stack (``current_span()`` lets
-    inner code annotate the enclosing interval)."""
+    inner code annotate the enclosing interval). Also a profiler annotation
+    of the same name while a profiler trace is running."""
     sp = Span(name, dict(args), _RECORDER, on_stack=True)
     _stack().append(sp)
     try:
-        yield sp
+        with phase(name):
+            yield sp
     finally:
         sp.end()
 
@@ -151,23 +157,23 @@ def current_span():
 
 
 # ---------------------------------------------------------------------------
-# energy attribution
+# profiler-only phases
 # ---------------------------------------------------------------------------
-def plan_energy_per_token(plan) -> float:
-    """Joules/token a deployed ``PrecisionPlan`` models: each GEMM site's
-    traced MAC count folded through ``core.energy.gemm_power`` for the site's
-    ⟨format, accumulator⟩, divided by the calibration token count recorded in
-    ``meta["envelope"]["traced_tokens"]``. Returns 0.0 when the plan predates
-    envelopes (no traced token count → no honest per-token rate)."""
-    env = (plan.meta or {}).get("envelope") or {}
-    tokens = env.get("traced_tokens")
-    if not tokens:
-        return 0.0
-    from repro.core.energy import gemm_power   # lazy: keep obs import-light
-    total = 0.0
-    for s in plan.gemm_sites():
-        if s.energy_j is not None:
-            total += s.energy_j
-        elif s.macs:
-            total += gemm_power(s.cfg.fmt, s.cfg.acc).energy_joules(s.macs)
-    return total / float(tokens)
+_NO_PHASE = contextlib.nullcontext()
+_ANNOTATION = None                  # jax.profiler.TraceAnnotation, on first use
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:         # lazy: keep this module import-light
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def phase(name: str):
+    """A named hot-path phase (``batcher.launch``, ``serving.feed``): a
+    ``jax.profiler.TraceAnnotation`` while a profiler trace is running,
+    else a shared no-op context. Nothing goes to the recorder."""
+    ann = _ANNOTATION or _annotation()
+    return ann(name) if ann.is_enabled() else _NO_PHASE

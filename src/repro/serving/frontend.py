@@ -10,7 +10,9 @@ budget admits (parking the rest — never the old silent truncation), recycles
 drained engines whose cursor ran out of room, steps every live engine, and
 resolves futures as requests finish. Streaming requests get their tokens
 through ``on_token`` callbacks from inside the decode step that produced
-them.
+them. Each tick's four parts are profiler phases (``serving.activate``,
+``serving.feed``, ``serving.step_live``, ``serving.harvest``), and each
+request's ``serving.request`` span carries its queue wait (``queue_ms``).
 
 Typed failure surface: ``RoutingError`` (no plan satisfies the request) and
 ``AdmissionError`` (no bucket fits / queue at cap) resolve the future as
@@ -26,7 +28,7 @@ from typing import Callable, Optional
 
 from repro.launch.batching import Request
 from repro.obs.registry import default_registry
-from repro.obs.spans import plan_energy_per_token, span, start_span
+from repro.obs.spans import phase, span, start_span
 from .engine import AdmissionError, BucketedEnginePool, GenerateEngine
 from .router import PlanRouter, RoutingError
 
@@ -50,7 +52,9 @@ class Completion:
     """Per-request completion future (host-side: the loop is cooperative).
     ``result()`` returns generated tokens (generate/stream) or the prompt
     log-probability (score); rejected/failed requests re-raise their typed
-    error."""
+    error. ``submitted_at`` and ``admitted_at`` are ``time.perf_counter()``
+    readings: when ``submit`` took the request, and when the loop handed it
+    to its engine (``None`` until then); the difference is its queue wait."""
 
     def __init__(self, request: ServeRequest):
         self.request = request
@@ -63,6 +67,8 @@ class Completion:
         self.steps = 0
         self.prefill_tokens = 0
         self.decode_tokens = 0
+        self.submitted_at = time.perf_counter()
+        self.admitted_at: Optional[float] = None
         self._span = None                 # serving.request lifecycle span
 
     @property
@@ -80,6 +86,15 @@ class Completion:
     def _reject(self, err: Exception) -> "Completion":
         self.error, self.done = err, True
         return self
+
+    def _admit(self) -> None:
+        """Stamp the hand-over to an engine (and the queue wait on the
+        request's span)."""
+        self.admitted_at = time.perf_counter()
+        if self._span is not None:
+            self._span.annotate(
+                admitted=True,
+                queue_ms=1e3 * (self.admitted_at - self.submitted_at))
 
 
 class RoutedFrontend:
@@ -110,12 +125,6 @@ class RoutedFrontend:
             "tokens processed by the serving loop", ("workload", "kind"))
         self._m_parked = reg.gauge(
             "repro_serving_parked", "requests parked in group queues")
-        self._m_run = reg.histogram(
-            "repro_serving_run_seconds", "RoutedFrontend.run() wall time")
-        self._m_energy = reg.counter(
-            "repro_serving_energy_joules_total",
-            "modeled GEMM energy attributed to completed requests", ("plan",))
-        self._energy_per_token: dict = {}     # plan name -> J/token (cached)
 
     # -- submission ---------------------------------------------------------
     def submit(self, req: ServeRequest) -> Completion:
@@ -168,10 +177,14 @@ class RoutedFrontend:
             for _ in range(max_steps):
                 if not self._groups and not self._inflight:
                     break
-                activated = self._activate_groups()
-                self._feed_live()
-                progressed = self._step_live()
-                self._harvest()
+                with phase("serving.activate"):
+                    activated = self._activate_groups()
+                with phase("serving.feed"):
+                    self._feed_live()
+                with phase("serving.step_live"):
+                    progressed = self._step_live()
+                with phase("serving.harvest"):
+                    self._harvest()
                 if progressed or activated:
                     idle_ticks = 0
                     continue
@@ -186,9 +199,7 @@ class RoutedFrontend:
             else:
                 raise RuntimeError(
                     f"frontend did not drain in {max_steps} steps")
-        dt = time.perf_counter() - t0
-        self._wall += dt
-        self._m_run.observe(dt)
+        self._wall += time.perf_counter() - t0
         self._m_parked.set(float(self._queued()))
         return self._completed[resolved_before:]
 
@@ -218,6 +229,8 @@ class RoutedFrontend:
         eng = self.pool.get(self.router[plan_name], bucket, "score")
         while q:
             batch = [q.popleft() for _ in range(min(len(q), bucket.n_slots))]
+            for comp in batch:
+                comp._admit()
             scores = eng.score_batch([c.request.prompt for c in batch])
             for comp, s in zip(batch, scores):
                 comp.score, comp.done = s, True
@@ -230,7 +243,6 @@ class RoutedFrontend:
                 self._m_requests.inc(workload=wl, event="completed")
                 self._m_tokens.inc(len(comp.request.prompt),
                                    workload=wl, kind="prefill")
-                self._attribute_energy(comp, len(comp.request.prompt))
                 if comp._span is not None:
                     comp._span.end(status="completed")
 
@@ -260,8 +272,7 @@ class RoutedFrontend:
                 self._inflight[comp.request.uid] = (comp, raw)
                 self._m_requests.inc(workload=comp.request.workload,
                                      event="routed")
-                if comp._span is not None:
-                    comp._span.annotate(admitted=True)
+                comp._admit()
                 eng.admit(raw)
             if not q:
                 self._groups.pop(key, None)
@@ -294,8 +305,6 @@ class RoutedFrontend:
             self._m_tokens.inc(raw.prefill_tokens, workload=wl,
                                kind="prefill")
             self._m_tokens.inc(raw.decode_tokens, workload=wl, kind="decode")
-            self._attribute_energy(comp,
-                                   raw.prefill_tokens + raw.decode_tokens)
             if comp._span is not None:
                 comp._span.end(status="completed", steps=raw.steps,
                                decode_tokens=raw.decode_tokens)
@@ -305,27 +314,6 @@ class RoutedFrontend:
             del self._live[key]
 
     # -- reporting ----------------------------------------------------------
-    def _attribute_energy(self, comp: Completion, tokens: int) -> None:
-        """Charge a completed request's modeled GEMM energy to its plan:
-        per-token joules come from the plan's calibration envelope
-        (``obs.plan_energy_per_token``). Derived variants without a plan
-        document on disk attribute 0 — they carry no envelope."""
-        if not comp.plan or tokens <= 0:
-            return
-        jpt = self._energy_per_token.get(comp.plan)
-        if jpt is None:
-            jpt = 0.0
-            rp = self.router._by_name.get(comp.plan)
-            if rp is not None and rp.path is not None:
-                try:
-                    from repro.numerics import load_plan
-                    jpt = plan_energy_per_token(load_plan(rp.path))
-                except (OSError, ValueError, KeyError):
-                    jpt = 0.0
-            self._energy_per_token[comp.plan] = jpt
-        if jpt:
-            self._m_energy.inc(jpt * tokens, plan=comp.plan)
-
     def metrics(self) -> dict:
         """Request-accounting snapshot with a closed-sum invariant:
         ``submitted == routed + parked + rejected`` — every submitted request
@@ -342,7 +330,6 @@ class RoutedFrontend:
             "submitted": submitted, "routed": routed, "parked": parked,
             "rejected": rejected, "completed": completed,
             "inflight": len(self._inflight),
-            "energy_joules": self._m_energy.total(),
             "wall_seconds": self._wall,
         }
 
