@@ -2,9 +2,11 @@
 
 A slot-based scheduler in the vLLM style, shaped for JAX: the decode step is
 compiled ONCE for a fixed (n_slots, max_len) cache; requests stream in and
-out of slots between steps (host-side bookkeeping, device-side state is
-donated through the jitted step). Finished slots are refilled immediately —
-the decode batch never drains while work is queued.
+out of slots between steps (host-side bookkeeping). The cache, the step's
+second argument, is donated to the jitted step: the KV stack is updated in
+place and the step's output cache takes over its buffers, so the engine
+holds one cache, never two. Finished slots are refilled immediately — the
+decode batch never drains while work is queued.
 
 This is the production serving loop for the framework; `examples/serve_batch`
 uses the simple whole-batch variant, `tests/test_serving.py` exercises this
@@ -114,14 +116,13 @@ class ContinuousBatcher:
         self.active: list[Optional[Request]] = [None] * n_slots
         # per-slot progress: how many prompt tokens already fed
         self._fed = np.zeros(n_slots, dtype=np.int64)
-        self.cache = init_cache(cfg, n_slots, max_len, dtype=jnp.float32)
         # the write cursor cache["len"] is global; each slot masks its
         # attention to [start[slot], len) so reused slots never see the
         # previous occupant's KV. ``_len`` mirrors the cursor host-side so
         # admission control never forces a device sync.
+        self.cache = self._empty_cache()
         self._len = 0
         self._start = np.zeros(n_slots, dtype=np.int32)
-        self.cache["start"] = jnp.zeros((n_slots,), jnp.int32)
         # traced exactly once per engine when warmed up — the regression
         # guard for "warmup must compile under the serving policy"
         self.trace_count = 0
@@ -139,12 +140,13 @@ class ContinuousBatcher:
 
         # the weights are an argument, not a closure: a closed-over array
         # becomes a constant of the executable, and every (plan, bucket)
-        # engine of a pool would carry its own copy of the model
+        # engine of a pool would carry its own copy of the model. The cache
+        # is donated: after a step only the returned cache is valid.
         def _step_fn(p, c, t):
             self.trace_count += 1            # python side effect: trace-time only
             return decode_step(p, cfg, c, t, dist)
 
-        self._step = jax.jit(_step_fn)
+        self._step = jax.jit(_step_fn, donate_argnums=(1,))
         if warmup:
             # AOT-compile the decode step before the first request arrives.
             # Tracing it resolves every GEMM call-site's GemmPlan (the plan
@@ -181,11 +183,17 @@ class ContinuousBatcher:
             raise RuntimeError("reset_cache with live slots would destroy "
                                "in-flight generations; drain first")
         with phase("batcher.reset_cache"):
-            self.cache = init_cache(self.cfg, self.n_slots, self.max_len,
-                                    dtype=jnp.float32)
+            # the old cache goes first, so two never share the device
+            self.cache = None
+            self.cache = self._empty_cache()
             self._len = 0
             self._start[:] = 0
-            self.cache["start"] = jnp.zeros((self.n_slots,), jnp.int32)
+
+    def _empty_cache(self) -> dict:
+        cache = init_cache(self.cfg, self.n_slots, self.max_len,
+                           dtype=jnp.float32)
+        cache["start"] = jnp.zeros((self.n_slots,), jnp.int32)
+        return cache
 
     def stats(self):
         """Typed ``PlanCacheStats`` for the process-global GemmPlan cache —

@@ -45,8 +45,10 @@ def serve(cfg, params, prompts, gen_len: int, dist=LOCAL):
         batch["frames"] = jnp.zeros((B, cfg.enc_seq, cfg.d_model))
     last_logits, cache = prefill(params, cfg, batch, cache, dist)
 
-    # weights as an argument, so the executable carries no copy of them
-    step = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t, dist))
+    # weights as an argument, so the executable carries no copy of them;
+    # the cache is donated, so each step updates it in place
+    step = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t, dist),
+                   donate_argnums=(1,))
 
     out = []
     tok = jnp.argmax(last_logits, axis=-1)[:, None].astype(jnp.int32)

@@ -223,6 +223,18 @@ def decode_attention(q: Array, k: Array, v: Array, *, cache_len: Array,
     return out.reshape(B, H, Sq, hd).astype(q.dtype)
 
 
+def _write_row(cache: Array, row: Array, pos: Array,
+               layer: Optional[Array] = None) -> Array:
+    """``row`` (B, Hkv, S, ...) written into ``cache`` (B, Hkv, Smax, ...)
+    at sequence position ``pos``; with ``layer``, into that layer of the
+    stacked cache (n_layers, B, Hkv, Smax, ...)."""
+    row = row.astype(cache.dtype)
+    if layer is None:
+        return jax.lax.dynamic_update_slice_in_dim(cache, row, pos, axis=2)
+    start = (layer, 0, 0, pos) + (0,) * (cache.ndim - 4)
+    return jax.lax.dynamic_update_slice(cache, row[None], start)
+
+
 def quantize_kv(x: Array):
     """Per-position symmetric int8 quantization: x (B, Hkv, S, hd) ->
     (int8 values, scales (B, Hkv, S))."""
@@ -263,7 +275,10 @@ def attention_block(x: Array, p, cfg, dist: Distribution, *,
                     site: str = "attn"):
     """Full attention sub-block. Returns (out, new_kv_cache | None).
 
-    kv_cache: {"k": (B,Hkv,Smax,hd), "v": ..., "len": int32[B?]} for decode.
+    kv_cache: {"k": (B,Hkv,Smax,hd), "v": ..., "len": int32[B?]} for decode,
+    or the stacked leaves (n_layers,B,Hkv,Smax,hd) plus "layer": the index
+    of this layer, whose row is written into the stack (the new cache then
+    holds the whole stack).
     kv_override: precomputed (k, v) (whisper cross-attention).
     """
     B, S, d = x.shape
@@ -296,37 +311,39 @@ def attention_block(x: Array, p, cfg, dist: Distribution, *,
 
     new_cache = None
     if kv_cache is not None:
-        # incremental decode: write k,v at position len, attend to prefix.
-        # The writes run under a ``kv_cache`` scope, so a profile names them.
-        ln = kv_cache["len"]
-        if "k_scale" in kv_cache:      # int8 tailored cache
-            with jax.named_scope("kv_cache"):
+        # incremental decode: write k,v at position len, attend to prefix,
+        # under a ``kv_cache`` scope so a profile names the cache's time.
+        # With a "layer" index the leaves are the whole stack (n_layers, B,
+        # Hkv, Smax, ...), carried by the layer scan and updated in place.
+        # Attention reads this layer's slice, taken before the stack is
+        # written, with the row written into it; the stack then takes the
+        # row back from that slice. The order matters to the compilers: with
+        # the read after the write, the CPU copies the whole stack to keep
+        # the read intact, and the TPU relays the whole stack for attention
+        # at every step's entry and exit.
+        ln, layer = kv_cache["len"], kv_cache.get("layer")
+        with jax.named_scope("kv_cache"):
+            if "k_scale" in kv_cache:      # int8 tailored cache
                 kq, ks = quantize_kv(k)
                 vq, vs = quantize_kv(v)
-                kfull = jax.lax.dynamic_update_slice_in_dim(
-                    kv_cache["k"], kq, ln, axis=2)
-                vfull = jax.lax.dynamic_update_slice_in_dim(
-                    kv_cache["v"], vq, ln, axis=2)
-                ksf = jax.lax.dynamic_update_slice_in_dim(
-                    kv_cache["k_scale"], ks.astype(kv_cache["k_scale"].dtype),
-                    ln, axis=2)
-                vsf = jax.lax.dynamic_update_slice_in_dim(
-                    kv_cache["v_scale"], vs.astype(kv_cache["v_scale"].dtype),
-                    ln, axis=2)
-            out = decode_attention(q, kfull, vfull, cache_len=ln + S,
-                                   k_scale=ksf, v_scale=vsf,
-                                   start=kv_cache.get("start"), site=site)
-            new_cache = {"k": kfull, "v": vfull, "k_scale": ksf,
-                         "v_scale": vsf, "len": ln + S}
-        else:
-            with jax.named_scope("kv_cache"):
-                kfull = jax.lax.dynamic_update_slice_in_dim(
-                    kv_cache["k"], k, ln, axis=2)
-                vfull = jax.lax.dynamic_update_slice_in_dim(
-                    kv_cache["v"], v, ln, axis=2)
-            out = decode_attention(q, kfull, vfull, cache_len=ln + S,
-                                   start=kv_cache.get("start"), site=site)
-            new_cache = {"k": kfull, "v": vfull, "len": ln + S}
+                rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+            else:
+                rows = {"k": k, "v": v}
+            if layer is None:
+                cur = new_cache = {n: _write_row(kv_cache[n], r, ln)
+                                   for n, r in rows.items()}
+            else:
+                cur = {n: _write_row(jax.lax.dynamic_index_in_dim(
+                    kv_cache[n], layer, 0, keepdims=False), r, ln)
+                    for n, r in rows.items()}
+                new_cache = {n: _write_row(
+                    kv_cache[n], jax.lax.dynamic_slice_in_dim(c, ln, S, 2),
+                    ln, layer) for n, c in cur.items()}
+        out = decode_attention(q, cur["k"], cur["v"], cache_len=ln + S,
+                               k_scale=cur.get("k_scale"),
+                               v_scale=cur.get("v_scale"),
+                               start=kv_cache.get("start"), site=site)
+        new_cache["len"] = ln + S
     else:
         out = attention(q, k, v, causal=causal, chunk=cfg.attn_chunk,
                         prefix_len=prefix_len, site=site)

@@ -331,24 +331,25 @@ def decode_step(params, cfg: ModelConfig, cache: dict, tokens: Array,
     x = _embed(params, cfg, tokens, dist)
     pos = cache["len"] + jnp.zeros((x.shape[0], 1), jnp.int32)
     ln = cache["len"]
-
-    def layer_cache(sl, dtype_tree):
-        return jax.tree.map(lambda c: c, sl)
-
     kv_keys = [k for k in ("k", "v", "k_scale", "v_scale")
                if k in cache.get("layers", {})]
     slot_start = cache.get("start")      # (B,) continuous-batching lower bound
 
     if cfg.family in ("dense", "moe", "vlm"):
-        def body(h, lc):
-            kv = {k: lc[k] for k in kv_keys} | {"len": ln,
-                                                "start": slot_start}
-            h, nc = _decoder_block(h, lc["p"], cfg, dist, positions=pos,
+        # The stacked cache rides in the carry and each layer writes its row
+        # into it: with the cache donated, the step updates it in place. As
+        # scan xs/ys every layer's slice would be copied out and back.
+        def body(c, xs):
+            h, stack = c
+            i, lp = xs
+            kv = stack | {"len": ln, "start": slot_start, "layer": i}
+            h, nc = _decoder_block(h, lp, cfg, dist, positions=pos,
                                    kv_cache=kv, moe_impl=moe_impl)
-            return h, {k: nc[k] for k in kv_keys}
+            return (h, {k: nc[k] for k in kv_keys}), None
 
-        carry, new_layers = jax.lax.scan(
-            body, x, {"p": params["layers"], **cache["layers"]})
+        (carry, new_layers), _ = jax.lax.scan(
+            body, (x, {k: cache["layers"][k] for k in kv_keys}),
+            (jnp.arange(cfg.n_layers), params["layers"]))
         new_cache = {"len": ln + 1, "layers": new_layers}
         if slot_start is not None:
             new_cache["start"] = slot_start

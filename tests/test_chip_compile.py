@@ -1,6 +1,7 @@
 """Compile rehearsal for TPU v5e: the Pallas FDP kernels at qwen3-0.6b widths
-compile for a described (not attached) v5e chip, and every tile the plan
-layer can hand them meets the TPU tiling rule.
+compile for a described (not attached) v5e chip, every tile the plan
+layer can hand them meets the TPU tiling rule, and the decode step updates
+its donated KV cache in place.
 
 Nothing runs on a chip here: the TPU compiler refuses what the chip would
 refuse (misaligned blocks, gathers Mosaic cannot lower, VMEM overruns), and
@@ -146,3 +147,54 @@ def test_heuristic_plan_is_fitted():
                     (256, D, DFF)):
         p = _heuristic_plan(1, m, n, k)
         assert p.fit(m, n, k) == p
+
+
+# The decode step's KV stack at the chat and solve engines' sizes, cut to
+# four layers: (policy, slots, max_len)
+STEPS = {"native": ("mxu_fp32", 8, 1024), "fdp91": ("fdp91", 4, 512)}
+
+
+@pytest.mark.parametrize("name", list(STEPS))
+def test_decode_step_writes_kv_rows_in_place_for_v5e(one_chip, no_cache,
+                                                     name):
+    """With the cache donated, the compiled step aliases every cache leaf,
+    copies no stack, and writes into each stack only one position's row:
+    the stacks keep their layout through the layer scan."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.core.dispatch import FDP91, MXU_FP32, use_policy
+    from repro.models import decode_step, init, init_cache
+
+    policy, slots, max_len = STEPS[name]
+    policy = {"mxu_fp32": MXU_FP32, "fdp91": FDP91}[policy]
+    cfg = dataclasses.replace(get_config("qwen3-0.6b"), n_layers=4)
+
+    def cache():
+        c = init_cache(cfg, slots, max_len, dtype=jnp.float32)
+        return c | {"start": jnp.zeros((slots,), jnp.int32)}
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), jax.eval_shape(tree))
+
+    params, c = on_chip(lambda: init(cfg, jax.random.key(0))), on_chip(cache)
+    tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    with use_policy(policy):
+        hlo = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t),
+                      donate_argnums=(1,)).lower(params, c, tok).compile(
+                      ).as_text()
+    n_params, n_cache = len(jax.tree.leaves(params)), len(jax.tree.leaves(c))
+    aliased = {int(n) for n in re.findall(r"\{[\d,]*\}: \((\d+), \{",
+                                          hlo.split("\n", 1)[0])}
+    assert set(range(n_params, n_params + n_cache)) <= aliased
+    k = c["layers"]["k"]
+    stack = f"f32[{','.join(map(str, k.shape))}]"
+    made = re.findall(rf"= {re.escape(stack)}\S* ([\w-]+)\(", hlo)
+    assert "copy" not in made and "custom-call" not in made, made
+    rows = re.findall(rf"= {re.escape(stack)}\S* dynamic-update-slice\("
+                      rf"%[\w.-]+, %([\w.-]+),", hlo)
+    shape_of = dict(re.findall(r"%([\w.-]+) = (\w+\[[\d,]*\])", hlo))
+    row = f"f32[1,{slots},{cfg.n_kv_heads},1,{cfg.head_dim}]"
+    assert len(rows) == 2 and {shape_of[r] for r in rows} == {row}, rows
