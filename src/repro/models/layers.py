@@ -94,10 +94,13 @@ def dense(x: Array, w: Array, site: str, bias: Optional[Array] = None,
     and the weight gradient (one flattened Aᵀ·G GEMM) as ``<site>@bwd.dB``.
 
     ``plan`` pins Pallas block sizes for this call-site; by default the
-    dispatch layer resolves one from its GemmPlan cache per operand shape."""
+    dispatch layer resolves one from its GemmPlan cache per operand shape.
+    The bias add runs under the site's scope too, so a profile charges it
+    to the site."""
     out = dispatch.gemm(x, w, site=site, plan=plan)
     if bias is not None:
-        out = out + bias
+        with jax.named_scope(dispatch.GemmSite.parse(site).scope):
+            out = out + bias
     return out.astype(x.dtype)
 
 

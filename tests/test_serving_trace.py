@@ -88,6 +88,15 @@ def test_every_site_is_a_scope_in_the_compiled_step(tiny, policy):
     assert "kv_cache" in scopes
 
 
+def test_bias_add_is_charged_to_its_site():
+    """A projection's bias add runs under its site's scope, not beside it."""
+    cfg = get_config("qwen1.5-4b").reduced()
+    eng = ContinuousBatcher(cfg, init(cfg, jax.random.key(0)), n_slots=2,
+                            max_len=16, warmup=MXU_FP32)
+    assert re.search(r'add\(.*op_name="[^"]*/site\.attn_q\.fwd/add"',
+                     eng.step_hlo_text())
+
+
 def test_backward_sites_are_scopes_too():
     """fwd and both bwd GEMMs of gemm, grouped_qk/av and ragged_gemm run
     under their site's scope (read from the lowered module's locations: the
