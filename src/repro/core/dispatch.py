@@ -807,6 +807,37 @@ def gemm(a: Array, b: Array, *, site: Union[str, GemmSite] = "generic",
 
 
 # -- grouped attention einsums ----------------------------------------------
+_ROW_PAD = _obs_registry().counter(
+    "repro_dispatch_row_pad_total",
+    "native grouped attention contractions traced with one row per (batch, "
+    "kv head), given a zero second row to stay a dot", ("site",))
+
+
+def _grouped_native(site: GemmSite, cfg: GemmConfig, subscripts: str,
+                    lhs: Array, rhs: Array) -> Array:
+    """The native einsum of ``grouped_qk``/``grouped_av``: lhs
+    (B,Kh,G,Sq,x) against rhs (B,Kh,Sk,hd), f32 out.
+
+    With one row per (batch, kv head), ``G * Sq == 1`` (MHA decode), XLA
+    rewrites the dot into an f32 multiply-reduce whose operand layouts make
+    the TPU relay out the whole K/V slice it reads, at every call. A zero
+    second row on the G axis keeps the contraction a dot that reads K/V as
+    it lies; the real row is sliced back out. It runs at ``HIGHEST``, as
+    the multiply-reduce it replaces ran in full f32."""
+    dt = cfg.fmt.jnp_dtype
+    lhs, rhs = lhs.astype(dt), rhs.astype(dt)
+    with jax.named_scope(site.scope):
+        if lhs.shape[2] * lhs.shape[3] != 1:
+            return jnp.einsum(subscripts, lhs, rhs,
+                              preferred_element_type=jnp.float32)
+        _ROW_PAD.inc(site=site.key)
+        lhs = jnp.pad(lhs, ((0, 0), (0, 0), (0, 1), (0, 0), (0, 0)))
+        out = jnp.einsum(subscripts, lhs, rhs,
+                         preferred_element_type=jnp.float32,
+                         precision=jax.lax.Precision.HIGHEST)
+        return out[:, :, :1]
+
+
 def _grouped_qk_execute(site: GemmSite, cfg: GemmConfig,
                         q: Array, k: Array) -> Array:
     """q (B,Kh,G,Sq,hd) x k (B,Kh,Sk,hd) -> (B,Kh,G,Sq,Sk).
@@ -816,10 +847,7 @@ def _grouped_qk_execute(site: GemmSite, cfg: GemmConfig,
     sequence dim). Simulate/pallas modes run the flattened 2D dispatch."""
     _note_site(site.key)
     if cfg.mode == "native":
-        dt = cfg.fmt.jnp_dtype
-        with jax.named_scope(site.scope):
-            out = jnp.einsum("bkgqd,bksd->bkgqs", q.astype(dt), k.astype(dt),
-                             preferred_element_type=jnp.float32)
+        out = _grouped_native(site, cfg, "bkgqd,bksd->bkgqs", q, k)
         if _TRACE_HOOK is not None:
             # report in jnp.matmul shape so the profiler sees the real
             # contraction: (B,Kh,G*Sq,hd) x (B,Kh,hd,Sk)
@@ -839,10 +867,7 @@ def _grouped_av_execute(site: GemmSite, cfg: GemmConfig,
     """p (B,Kh,G,Sq,Sk) x v (B,Kh,Sk,hd) -> (B,Kh,G,Sq,hd)."""
     _note_site(site.key)
     if cfg.mode == "native":
-        dt = cfg.fmt.jnp_dtype
-        with jax.named_scope(site.scope):
-            out = jnp.einsum("bkgqs,bksd->bkgqd", p.astype(dt), v.astype(dt),
-                             preferred_element_type=jnp.float32)
+        out = _grouped_native(site, cfg, "bkgqs,bksd->bkgqd", p, v)
         if _TRACE_HOOK is not None:
             B_, Kh_, G_, Sq_, Sk_ = p.shape
             _maybe_trace(site.key, cfg, p.reshape(B_, Kh_, G_ * Sq_, Sk_), v,

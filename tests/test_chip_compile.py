@@ -11,6 +11,7 @@ tests and only the worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -150,26 +151,49 @@ def test_heuristic_plan_is_fitted():
 
 
 # The decode step's KV stack at the chat and solve engines' sizes, cut to
-# four layers: (policy, slots, max_len)
-STEPS = {"native": ("mxu_fp32", 8, 1024), "fdp91": ("fdp91", 4, 512)}
+# four layers: (model, policy, slots, max_len). qwen3-0.6b's attention is
+# grouped (G = 2); qwen1.5-4b's is MHA (G = 1), one row per (slot, head).
+STEPS = {"native": ("qwen3-0.6b", "mxu_fp32", 8, 1024),
+         "fdp91": ("qwen3-0.6b", "fdp91", 4, 512),
+         "mha_native": ("qwen1.5-4b", "mxu_fp32", 8, 1024)}
+
+
+def _row_pads() -> float:
+    from repro.obs.registry import default_registry
+    counter = default_registry().get("repro_dispatch_row_pad_total")
+    return 0.0 if counter is None else counter.total()
+
+
+def _strip_metadata(hlo: str) -> str:
+    """The HLO text without op metadata and the source-location tables,
+    which name the Python functions and lines that traced each op."""
+    hlo = re.sub(r", metadata=\{[^}]*\}", "", hlo)
+    head, _, tables = hlo.partition("\nFileNames")
+    return head + (tables[tables.index("\n%"):] if tables else "")
 
 
 @pytest.mark.parametrize("name", list(STEPS))
 def test_decode_step_writes_kv_rows_in_place_for_v5e(one_chip, no_cache,
-                                                     name):
+                                                     name, monkeypatch):
     """With the cache donated, the compiled step aliases every cache leaf,
     copies no stack, and writes into each stack only one position's row:
-    the stacks keep their layout through the layer scan."""
+    the stacks keep their layout through the layer scan.
+
+    MHA (G = 1): the attention contractions stay dots at ``HIGHEST`` that
+    read each layer's K/V slice as it lies, so no slice is copied or relaid
+    out and the temporaries are the weight converts. GQA: the one-row path
+    never engages, and the step compiles to the instructions of a plain
+    einsum."""
     import dataclasses
-    import re
 
     from repro.configs import get_config
+    from repro.core import dispatch
     from repro.core.dispatch import FDP91, MXU_FP32, use_policy
     from repro.models import decode_step, init, init_cache
 
-    policy, slots, max_len = STEPS[name]
+    arch, policy, slots, max_len = STEPS[name]
     policy = {"mxu_fp32": MXU_FP32, "fdp91": FDP91}[policy]
-    cfg = dataclasses.replace(get_config("qwen3-0.6b"), n_layers=4)
+    cfg = dataclasses.replace(get_config(arch), n_layers=4)
 
     def cache():
         c = init_cache(cfg, slots, max_len, dtype=jnp.float32)
@@ -181,10 +205,17 @@ def test_decode_step_writes_kv_rows_in_place_for_v5e(one_chip, no_cache,
 
     params, c = on_chip(lambda: init(cfg, jax.random.key(0))), on_chip(cache)
     tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
-    with use_policy(policy):
-        hlo = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t),
-                      donate_argnums=(1,)).lower(params, c, tok).compile(
-                      ).as_text()
+
+    def compile_step():
+        with use_policy(policy):
+            return jax.jit(lambda p, c, t: decode_step(p, cfg, c, t),
+                           donate_argnums=(1,)).lower(params, c, tok
+                                                      ).compile()
+
+    pads = _row_pads()
+    compiled = compile_step()
+    pads = _row_pads() - pads
+    hlo = compiled.as_text()
     n_params, n_cache = len(jax.tree.leaves(params)), len(jax.tree.leaves(c))
     aliased = {int(n) for n in re.findall(r"\{[\d,]*\}: \((\d+), \{",
                                           hlo.split("\n", 1)[0])}
@@ -198,3 +229,38 @@ def test_decode_step_writes_kv_rows_in_place_for_v5e(one_chip, no_cache,
     shape_of = dict(re.findall(r"%([\w.-]+) = (\w+\[[\d,]*\])", hlo))
     row = f"f32[1,{slots},{cfg.n_kv_heads},1,{cfg.head_dim}]"
     assert len(rows) == 2 and {shape_of[r] for r in rows} == {row}, rows
+
+    if cfg.n_heads == cfg.n_kv_heads:
+        assert pads > 0
+        # no op makes a copy of one layer's slice, in any layout
+        dims = ",".join(map(str, k.shape[1:]))
+        slice_ops = re.findall(rf"%([\w-]+?)(?:\.\d+)? = f32\[(?:1,)?{dims}\]"
+                               rf"\S* ([\w-]+)\(", hlo)
+        assert not [o for o in slice_ops if o[0].startswith("copy")
+                    or o[1].startswith("copy")], slice_ops
+        sites = ("site.attn_qk.fwd", "site.attn_av.fwd")
+        for site in sites:
+            ops = [ln for ln in hlo.splitlines()
+                   if f"/{site}/" in ln and " convolution(" in ln]
+            assert ops and all("operand_precision={highest,highest}" in ln
+                               for ln in ops), (site, ops)
+        assert not [ln for ln in hlo.splitlines()
+                    if "multiply_reduce_fusion" in ln.split("=")[0]
+                    and any(f"/{s}/" in ln for s in sites)]
+        weights = sum(x.size * 2 for x in jax.tree.leaves(params["layers"])
+                      if x.ndim == 3)     # the stacked matrices, as bf16
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp <= weights + (32 << 20), (temp, weights)
+        return
+    assert pads == 0
+    if policy is MXU_FP32:
+        def plain(site, cfg, subscripts, lhs, rhs):
+            dt = cfg.fmt.jnp_dtype
+            lhs, rhs = lhs.astype(dt), rhs.astype(dt)
+            with jax.named_scope(site.scope):
+                return jnp.einsum(subscripts, lhs, rhs,
+                                  preferred_element_type=jnp.float32)
+
+        monkeypatch.setattr(dispatch, "_grouped_native", plain)
+        assert _strip_metadata(compile_step().as_text()) == \
+            _strip_metadata(hlo)
